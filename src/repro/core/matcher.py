@@ -27,7 +27,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import pso
 from repro.core.graphs import Graph, as_device_graphs
 from repro.kernels import backend as kernel_backend
-from repro.runtime.sharding import get_shard_map
 
 
 @dataclasses.dataclass
@@ -238,9 +237,8 @@ def build_distributed_match(Q_shape: Tuple[int, int], mesh: Mesh,
         S_star=P(), f_star=P(), S_bar=P(), epochs_run=P(),
         carry_mapping=P(), carry_feasible=P(), prune_sweeps=P())
 
-    shard_map = get_shard_map()
-    fn = shard_map(local_match, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs)
+    fn = jax.shard_map(local_match, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return _mark_mesh_executable(jax.jit(fn))
 
 
@@ -289,9 +287,8 @@ def build_distributed_match_batch(Q_shape: Tuple[int, int], mesh: Mesh,
             S_star=shard_b, f_star=shard_b, S_bar=shard_b,
             epochs_run=shard_b, carry_mapping=shard_b,
             carry_feasible=shard_b, prune_sweeps=shard_b)
-        shard_map = get_shard_map()
-        fn = shard_map(local_match, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs)
+        fn = jax.shard_map(local_match, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return _mark_mesh_executable(jax.jit(fn))
 
     per_problem = build_distributed_match(Q_shape, mesh, cfg, axis_names)
@@ -330,8 +327,6 @@ def build_distributed_revalidate_batch(Q_shape: Tuple[int, int], mesh: Mesh,
     """
     axis_names = tuple(axis_names)
     num_shards = int(np.prod([mesh.shape[a] for a in axis_names]))
-    shard_map = get_shard_map()
-
     def local_reval(Qb, Gb, maskb, carry0):
         return pso._revalidate_batch_body(Qb, Gb, maskb, cfg, carry0)
 
@@ -347,8 +342,8 @@ def build_distributed_revalidate_batch(Q_shape: Tuple[int, int], mesh: Mesh,
         out_specs = dict(mapping=P(), ok=P(), ok_rebase=P(), fitness=P(),
                          S_star=P(), S_bar=P(), prune_sweeps=P(),
                          f_carry=P())
-    fn = shard_map(local_reval, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs)
+    fn = jax.shard_map(local_reval, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return _mark_mesh_executable(jax.jit(fn))
 
 

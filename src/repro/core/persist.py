@@ -16,12 +16,13 @@ module removes both cold components:
     kernel suite + every ``PSOConfig`` field — plus jax version and
     platform, so a config or toolchain drift is a clean cache miss, never
     a wrong program.
-  * **XLA compile cache fallback** (:func:`enable_jax_compilation_cache`)
+  * **XLA compile cache fallback** (:func:`enable_compilation_cache`)
     — mesh-sharded executables (``build_distributed_*``) cannot be
     exported portably (the serialized module pins device counts; the
     builders mark themselves ``aot_exportable = False``); for those, and
     for the residual XLA compile of deserialized modules, JAX's
-    persistent compilation cache is pointed at ``<persist_dir>/xla``.
+    persistent compilation cache is turned on at one fixed directory:
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``.
   * **Snapshot codecs** (:func:`encode_key` / :func:`decode_key`,
     :func:`carry_leaves` / :func:`carries_from_leaves`) — the service's
     snapshot (``MatcherService.save_snapshot``) stores warm-start carries
@@ -38,6 +39,8 @@ Environment knobs (all optional — constructor args win):
     stay on).
   * ``REPRO_JAX_CACHE=0`` — do not touch JAX's persistent compilation
     cache config even when a persist dir is set.
+  * ``JAX_COMPILATION_CACHE_DIR`` — read by JAX itself; when set, no
+    code here sets the cache directory.
 """
 from __future__ import annotations
 
@@ -70,38 +73,32 @@ def aot_cache_enabled() -> bool:
     return os.environ.get(ENV_AOT_CACHE, "1").strip() != "0"
 
 
-_jax_cache_dir: List[str] = []     # process-global: first enable wins
+#: The compile cache used when ``JAX_COMPILATION_CACHE_DIR`` is unset:
+#: one fixed directory in the checkout (listed in ``.gitignore``). The
+#: directory is part of every cache key, so it is never derived from a
+#: persist root, a temp name, a pid or the time.
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 
-def enable_jax_compilation_cache(directory: str) -> bool:
-    """Point JAX's persistent XLA compilation cache at ``directory``.
+def enable_compilation_cache() -> Optional[str]:
+    """Turn on JAX's persistent XLA compilation cache; return its dir.
 
     Covers what ``jax.export`` cannot: the XLA compile of a deserialized
-    module, and mesh-sharded executables that are never exported. The
-    min-compile-time/entry-size floors are zeroed so the service's small
-    revalidation programs qualify.
-
-    The cache dir is **process-global JAX state**, so the first enabled
-    directory wins for the process lifetime: a second service with a
-    different persist root returns False and leaves the existing cache
-    in place (re-pointing mid-process would scatter one service's
-    compiles across another's tree). Also returns False when the
-    running JAX build lacks the knobs or ``REPRO_JAX_CACHE=0``."""
+    module, and mesh-sharded executables that are never exported. When
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and the directory
+    is left alone; otherwise the cache is :data:`CHECKOUT_CACHE_DIR`.
+    The min-compile-time/entry-size floors are zeroed so the service's
+    small revalidation programs qualify. Returns None (and changes
+    nothing) under ``REPRO_JAX_CACHE=0``."""
     if os.environ.get(ENV_JAX_CACHE, "1").strip() == "0":
-        return False
-    if _jax_cache_dir:
-        return _jax_cache_dir[0] == directory
-    try:
-        jax.config.update("jax_compilation_cache_dir", directory)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:  # pragma: no cover - older/newer jax knob drift
-        return False
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:  # pragma: no cover
-        pass
-    _jax_cache_dir.append(directory)
-    return True
+        return None
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return jax.config.jax_compilation_cache_dir
 
 
 # ---------------------------------------------------------------------------

@@ -134,7 +134,7 @@ def elite_consensus(S_all, f_all, cfg: PSOConfig):
     Also returns (weighted_sum, weight_total) so the distributed matcher can
     psum the parts across devices before dividing. Thin wrapper over the
     backend seam (``KernelBackend.elite_consensus``) — the fused epoch
-    tail computes the same reduction in-kernel.
+    tail computes the same reduction beside its kernel launch.
     """
     bk = kernel_backend.for_config(cfg)
     k = max(1, int(round(cfg.elite_frac * S_all.shape[0])))

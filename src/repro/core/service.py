@@ -832,9 +832,11 @@ class MatcherService:
         commit, ``keep=snapshot_keep``). Snapshots are versioned and
         digest-validated: a restore against a drifted config is skipped
         cleanly (``snapshot_stale_skipped``), never mis-applied.
-      * ``<persist_dir>/xla/`` — JAX's persistent compilation cache is
-        enabled here (process-global; opt out with ``REPRO_JAX_CACHE=0``)
-        so the residual XLA compile of deserialized modules and of the
+      * JAX's persistent compilation cache is turned on (process-global;
+        opt out with ``REPRO_JAX_CACHE=0``) at ``JAX_COMPILATION_CACHE_DIR``
+        when set, else ``<checkout>/.jax_cache`` (see
+        :func:`~repro.core.persist.enable_compilation_cache`), so the
+        residual XLA compile of deserialized modules and of the
         non-exportable mesh executables is also served from disk.
     """
 
@@ -900,8 +902,7 @@ class MatcherService:
             self._ckpt = CheckpointManager(
                 os.path.join(self.persist_dir, "snapshots"),
                 async_save=False, keep=snapshot_keep)
-            persist.enable_jax_compilation_cache(
-                os.path.join(self.persist_dir, "xla"))
+            persist.enable_compilation_cache()
         if self._aot is not None and self.donate_buffers \
                 and not _DONATION_EXPORT_WARNED \
                 and not pallas_compat.export_preserves_donation():

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 _NEG = jnp.finfo(jnp.float32).min
 
@@ -70,7 +69,7 @@ def greedy_project_pallas(S: jax.Array, mask: jax.Array,
         out_specs=pl.BlockSpec((n, m), lambda k: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, m), jnp.uint8),
         scratch_shapes=[pltpu.VMEM((n, m), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(S, mask)

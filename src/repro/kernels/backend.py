@@ -219,11 +219,12 @@ class KernelBackend:
         """The entire epoch epilogue for ONE problem, fused.
 
         (Gumbel-perturbed) structured projection, greedy projection +
-        Ullmann candidate refinement, per-particle feasibility and the
-        elite consensus in one body. ``S``: (N, n, m) final swarm;
-        ``f_final``: (N,) the fused epoch kernel's last-step fitness
-        (threaded through instead of recomputed); ``gum``: (N, n, m)
-        pre-drawn Gumbel noise or ``None`` when ``gumbel_tau == 0``.
+        Ullmann candidate refinement and per-particle feasibility in one
+        kernel body; the elite consensus beside it. ``S``: (N, n, m)
+        final swarm; ``f_final``: (N,) the fused epoch kernel's
+        last-step fitness (threaded through instead of recomputed);
+        ``gum``: (N, n, m) pre-drawn Gumbel noise or ``None`` when
+        ``gumbel_tau == 0``.
         Returns ``(M_hat (N, n, m) uint8, feasible (N,) bool,
         S_bar (n, m) f32)``.
         """
@@ -280,8 +281,8 @@ class KernelBackend:
         particles (paper line 24). Returns ``(weighted, weight_total,
         w)`` so the distributed matcher can psum the parts across
         devices before dividing. The fused tail computes the same
-        reduction in-kernel; this standalone entry point serves the
-        mesh builders and any caller outside the epoch hot path."""
+        reduction beside its launch; this standalone entry point serves
+        the mesh builders and any caller outside the epoch hot path."""
         from repro.kernels.finish_fused import elite_consensus_reference
         return elite_consensus_reference(S_all, f_all, elite_k=elite_k,
                                          consensus_temp=consensus_temp)

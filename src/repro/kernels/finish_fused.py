@@ -6,9 +6,10 @@ an Ullmann candidate refinement, two feasibility checks, a redundant
 fitness recompute, and the elite-consensus reduction — ~7 separate XLA
 dispatches round-tripping the full particle state ``S`` (N, n, m)
 through HBM per epoch per problem. This kernel closes that fusion
-frontier: the ENTIRE epilogue of ``run_epoch`` runs in one body, so an
-epoch is exactly two kernel launches (``epoch_fused`` → this) with no
-host-visible intermediates between them.
+frontier: the projections, refinement and feasibility of ``run_epoch``
+run in one body, so an epoch is exactly two kernel launches
+(``epoch_fused`` → this) with no host-visible intermediates between
+them.
 
 Per problem the body computes, with ``S`` read from HBM once:
 
@@ -19,38 +20,34 @@ Per problem the body computes, with ``S`` read from HBM once:
      refinement** (``refine_iters`` matrix-form sweeps) + structured
      re-projection → ``M_b``;
   3. per-particle **feasibility** of both (rows/cols injective,
-     ``M G Mᵀ ⊇ Q``) and the ``feas_a ? M_a : M_b`` merge;
-  4. **elite consensus** ``S̄`` over the threaded-in final fitness
-     (the fused epoch kernel's ``f_last`` — the fitness recompute the
-     legacy epilogue did is gone).
+     ``M G Mᵀ ⊇ Q``) and the ``feas_a ? M_a : M_b`` merge.
+
+The **elite consensus** ``S̄`` over the threaded-in final fitness (the
+fused epoch kernel's ``f_last``) is a top-k, a softmax and one weighted
+sum; the ops layer computes it beside the launch with the ``ref``
+oracle itself (``elite_consensus_reference``), so it is bitwise the
+``ref`` value on every backend.
 
 Grid: ``(P,)`` problems, same layout discipline as the fused epoch
-kernel. Outputs are ``M_hat`` (P, N, n, m) int32 0/1, ``feasible``
-(P, N) int32 0/1 and ``S_bar`` (P, n, m) f32; the ops layer casts to
-the public uint8/bool dtypes.
+kernel. Outputs are ``M_hat`` (P, N, n, m) int32 0/1 and ``feasible``
+(P, N, 1, 1) int32 0/1; the ops layer casts to the public uint8/bool
+dtypes.
 
 Bitwise-parity engineering (the acceptance bar is bitwise equality
 with the pre-fusion epilogue on the ``ref`` ↔ ``interpret`` pair):
 
-* **No gather/scatter/top_k in-kernel.** ``.at[i, j].set`` becomes a
-  one-hot ``broadcasted_iota`` masked select (exact: values are 0/1
-  ints or written whole rows); ``S_all[top_k(f)]`` becomes ``elite_k``
-  statically-unrolled rounds of argmax + mask-to--inf, which matches
-  ``jax.lax.top_k``'s stable ordering (ties broken by lower index)
-  value-for-value and index-for-index.
+* **No gather/scatter/dynamic index in-kernel.** ``.at[i, j].set``
+  becomes a one-hot ``broadcasted_iota`` masked select, and reading row
+  ``i`` a one-hot masked reduction (exact: values are 0/1 ints, whole
+  rows, or a max over one live float).
 * **Flat argmax decomposition.** ``ref.masked_argmax`` argmaxes the
   flattened (n·m,) array; in-kernel this is (row-max, row-argmax,
   argmax over row-maxes) — the same first-maximum in row-major order,
   so ``greedy_project`` picks identical pivots.
-* **Batched int matmuls.** ``Q @ miss`` per particle becomes one
-  ``dot_general`` producing (N, m, n) plus a transpose — int32
-  accumulation is order-independent, hence exact even MXU-padded.
-* **Reductions mirror the vmapped-ref lowering** (sum/max over the
-  same axes with the same jnp ops), and the consensus softmax/einsum
-  are literally the ref ops on bitwise-identical inputs. The ops layer
-  runs interpret mode UNPADDED so f32 reduction grouping matches the
-  ``ref`` path exactly; the compiled path MXU-pads (exact for the int
-  projections/feasibility, allclose for the f32 consensus).
+* **Exact integer matmuls.** Every contraction is 0/1 × 0/1 and runs
+  through ``mxu.int_dot`` (bf16 operands, f32 accumulation — exact, and
+  the form Mosaic accepts on a v5e); ``Q @ miss`` per particle becomes
+  one (N, m, n) contraction plus a transpose.
 * **Padding correctness**: construction loops run ``n_rows`` (logical)
   trips, and the feasibility row check masks padded all-zero rows with
   a static ``iota >= n_rows`` escape; padded mask columns are zero so
@@ -66,7 +63,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.mxu import int_dot, vmem_limit
 
 _NEG = float(jnp.finfo(jnp.float32).min)
 
@@ -157,48 +154,48 @@ def _batched_structured(Sf, avail0, Qi, Gi, n_rows: int):
     """``ref.structured_project`` batched over the particle axis.
 
     ``Sf``: (N, n, m) f32 scores; ``avail0``: (N, n, m) int32 0/1
-    initial candidates; ``Qi``/``Gi``: shared int32 graphs. One-hot
-    masked selects replace every ``.at[]`` scatter and ``G[j]`` gather
-    (exact: whole int rows / 0-1 writes). Loops ``n_rows`` trips — the
-    LOGICAL query size, so MXU row padding never adds iterations.
+    initial candidates; ``Qi``/``Gi``: shared int32 graphs. Row ``i`` is
+    read by a one-hot masked reduction and written by a one-hot select,
+    in place of every ``.at[]`` scatter and dynamic index (exact: whole
+    int rows / 0-1 writes, and a max over one live float). Loops
+    ``n_rows`` trips — the LOGICAL query size, so row padding never adds
+    iterations.
     """
     N, n, m = Sf.shape
-    succ_need = jnp.sum(Qi, axis=1)                       # (n,) out-degree
+    succ_need = jnp.sum(Qi, axis=1, keepdims=True)        # (n, 1) out-degree
+    q_col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    q_row = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
     col_iota = jax.lax.broadcasted_iota(jnp.int32, (N, m), 1)
     row_iota3 = jax.lax.broadcasted_iota(jnp.int32, (N, n, m), 1)
-    col_iota3 = jax.lax.broadcasted_iota(jnp.int32, (N, n, m), 2)
 
     def body(i, state):
         avail, col_avail, out, img_rows = state
-        preds = jax.lax.dynamic_index_in_dim(Qi, i, 1, keepdims=False)
-        need = jnp.sum(preds)
+        row_i = row_iota3 == i
+        preds = jnp.sum(jnp.where(q_col == i, Qi, 0), axis=1,
+                        keepdims=True)                    # (n, 1) = Q[:, i]
+        need = jnp.sum(preds, axis=0, keepdims=True)      # (1, 1)
         # support[p, j] = preds @ img_rows[p] — how many of i's placed
         # predecessors have an edge to j's image neighbourhood
-        support = jnp.sum(img_rows * preds[None, :, None], axis=1)
+        support = jnp.sum(img_rows * preds[None], axis=1)  # (N, m)
         # forward checking: free out-neighbours of candidate j
-        free_out = jax.lax.dot_general(
-            col_avail, Gi, dimension_numbers=(((1,), (1,)), ((), ())))
-        avail_i = jax.lax.dynamic_index_in_dim(avail, i, 1, keepdims=False)
-        s_i = jax.lax.dynamic_index_in_dim(Sf, i, 1, keepdims=False)
-        succ_i = jax.lax.dynamic_index_in_dim(succ_need, i, 0,
-                                              keepdims=False)
+        free_out = int_dot(col_avail, Gi, (((1,), (1,)), ((), ())))
+        avail_i = jnp.sum(jnp.where(row_i, avail, 0), axis=1)
+        s_i = jnp.max(jnp.where(row_i, Sf, _NEG), axis=1)
+        succ_i = jnp.sum(jnp.where(q_row == i, succ_need, 0), axis=0,
+                         keepdims=True)                   # (1, 1)
         feas = ((avail_i > 0) & (support >= need) & (free_out >= succ_i))
         scores = jnp.where(feas, s_i, _NEG)               # (N, m)
-        j = jnp.argmax(scores, axis=-1)                   # (N,)
-        ok = jnp.max(scores, axis=-1) > _NEG              # (N,)
-        col_kill = ((col_iota != j[:, None]) | (~ok[:, None]))
-        new_avail = avail * col_kill[:, None, :].astype(jnp.int32)
-        new_col = col_avail * col_kill.astype(jnp.int32)
-        upd = ((row_iota3 == i) & (col_iota3 == j[:, None, None])
-               & ok[:, None, None])
-        new_out = jnp.where(upd, 1, out)
+        j = jnp.argmax(scores, axis=-1, keepdims=True)    # (N, 1)
+        ok = jnp.max(scores, axis=-1, keepdims=True) > _NEG
+        pick = (col_iota == j) & ok                       # (N, m) one-hot
+        keep = 1 - pick.astype(jnp.int32)
+        new_avail = avail * keep.reshape(N, 1, m)
+        new_col = col_avail * keep
+        new_out = jnp.where(row_i & pick.reshape(N, 1, m), 1, out)
         # img_rows[p, i] = ok ? Gi[j[p]] : 0 — row gather as a one-hot
-        # int matmul (picks exactly one row, int32 exact)
-        col_oh = (col_iota == j[:, None]).astype(jnp.int32)
-        Gi_j = jax.lax.dot_general(
-            col_oh, Gi, dimension_numbers=(((1,), (0,)), ((), ())))
-        new_val = jnp.where(ok[:, None], Gi_j, 0)          # (N, m)
-        new_img = jnp.where(row_iota3 == i, new_val[:, None, :], img_rows)
+        # matmul (picks exactly one 0/1 row)
+        Gi_j = int_dot(pick, Gi, (((1,), (0,)), ((), ())))
+        new_img = jnp.where(row_i, Gi_j.reshape(N, 1, m), img_rows)
         return new_avail, new_col, new_out, new_img
 
     col0 = jnp.ones((N, m), jnp.int32)
@@ -215,29 +212,23 @@ def _batched_greedy(Sf, avail0, n_rows: int):
     argmax decomposes into (row-max, row-argmax, argmax over row-maxes)
     — the identical first-maximum in row-major order."""
     N, n, m = Sf.shape
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (N, n), 1)
-    col_iota = jax.lax.broadcasted_iota(jnp.int32, (N, m), 1)
-    row_iota3 = jax.lax.broadcasted_iota(jnp.int32, (N, n, m), 1)
-    col_iota3 = jax.lax.broadcasted_iota(jnp.int32, (N, n, m), 2)
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (N, n, 1), 1)
+    col_iota = jax.lax.broadcasted_iota(jnp.int32, (N, 1, m), 2)
 
     def body(_, state):
         avail, out = state
         flat = jnp.where(avail != 0, Sf, _NEG)            # (N, n, m)
-        row_max = jnp.max(flat, axis=-1)                  # (N, n)
-        row_arg = jnp.argmax(flat, axis=-1)               # (N, n)
-        i_star = jnp.argmax(row_max, axis=-1)             # (N,)
-        val = jnp.max(row_max, axis=-1)                   # (N,)
-        j_star = jnp.sum(
-            jnp.where(row_iota == i_star[:, None], row_arg, 0), axis=-1)
-        take = val > _NEG
-        row_kill = ((row_iota != i_star[:, None]) | (~take[:, None]))
-        col_kill = ((col_iota != j_star[:, None]) | (~take[:, None]))
-        new_avail = (avail * row_kill[:, :, None].astype(jnp.int32)
-                     * col_kill[:, None, :].astype(jnp.int32))
-        upd = ((row_iota3 == i_star[:, None, None])
-               & (col_iota3 == j_star[:, None, None])
-               & take[:, None, None])
-        return new_avail, jnp.where(upd, 1, out)
+        row_max = jnp.max(flat, axis=-1, keepdims=True)   # (N, n, 1)
+        row_arg = jnp.argmax(flat, axis=-1, keepdims=True)
+        i_star = jnp.argmax(row_max, axis=1, keepdims=True)   # (N, 1, 1)
+        take = jnp.max(row_max, axis=1, keepdims=True) > _NEG
+        j_star = jnp.sum(jnp.where(row_iota == i_star, row_arg, 0),
+                         axis=1, keepdims=True)           # (N, 1, 1)
+        hit_row = (row_iota == i_star) & take             # (N, n, 1)
+        hit_col = (col_iota == j_star) & take             # (N, 1, m)
+        new_avail = (avail * (1 - hit_row.astype(jnp.int32))
+                     * (1 - hit_col.astype(jnp.int32)))
+        return new_avail, jnp.where(hit_row & hit_col, 1, out)
 
     out0 = jnp.zeros((N, n, m), jnp.int32)
     _, out = jax.lax.fori_loop(0, n_rows, body, (avail0, out0))
@@ -245,54 +236,51 @@ def _batched_greedy(Sf, avail0, n_rows: int):
 
 
 def _batched_sweep(Mi, Qi, Gi):
-    """``ref.ullmann_refine_step`` batched: int32 dot_generals with the
-    per-particle ``Q @ miss`` products built as (N, m, n) contractions
-    plus a transpose (int accumulation — order-independent, exact)."""
-    support_out = jax.lax.dot_general(
-        Mi, Gi, dimension_numbers=(((2,), (1,)), ((), ())))
-    support_in = jax.lax.dot_general(
-        Mi, Gi, dimension_numbers=(((2,), (0,)), ((), ())))
+    """``ref.ullmann_refine_step`` batched: exact 0/1 dot_generals with
+    the per-particle ``Q @ miss`` products built as (N, m, n)
+    contractions plus a transpose."""
+    support_out = int_dot(Mi, Gi, (((2,), (1,)), ((), ())))
+    support_in = int_dot(Mi, Gi, (((2,), (0,)), ((), ())))
     miss_out = (support_out == 0).astype(jnp.int32)
     miss_in = (support_in == 0).astype(jnp.int32)
-    viol_out = jax.lax.dot_general(
-        miss_out, Qi, dimension_numbers=(((1,), (1,)), ((), ())))
-    viol_in = jax.lax.dot_general(
-        miss_in, Qi, dimension_numbers=(((1,), (0,)), ((), ())))
+    viol_out = int_dot(miss_out, Qi, (((1,), (1,)), ((), ())))
+    viol_in = int_dot(miss_in, Qi, (((1,), (0,)), ((), ())))
     viol = (jnp.transpose(viol_out, (0, 2, 1))
             + jnp.transpose(viol_in, (0, 2, 1)))
     return Mi * (viol == 0).astype(jnp.int32)
 
 
 def _batched_feasible(Mi, Qi, Gi, n_rows: int):
-    """``ref.is_feasible`` batched over particles. Padded all-zero rows
-    are excused from the rows-sum-to-one check via a static
+    """``ref.is_feasible`` batched over particles, as an (N, 1, 1) 0/1
+    column. ``Mi`` comes from a projection, so each row holds at most
+    one 1 and ``M G`` stays 0/1 (exact on the MXU). Padded all-zero
+    rows are excused from the rows-sum-to-one check via a static
     ``iota >= n_rows`` escape (vacuous unpadded)."""
     N, n, m = Mi.shape
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (N, n), 1)
-    rows_sum = jnp.sum(Mi, axis=2)                        # (N, n)
-    cols_sum = jnp.sum(Mi, axis=1)                        # (N, m)
-    rows_ok = jnp.all((rows_sum == 1) | (row_iota >= n_rows), axis=-1)
-    cols_ok = jnp.all(cols_sum <= 1, axis=-1)
-    MG = jax.lax.dot_general(
-        Mi, Gi, dimension_numbers=(((2,), (0,)), ((), ())))
-    mapped = jax.lax.dot_general(
-        MG, Mi, dimension_numbers=(((2,), (2,)), ((0,), (0,))))
-    covers = jnp.all(mapped >= Qi[None], axis=(1, 2))
-    return rows_ok & cols_ok & covers
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (N, n, 1), 1)
+    rows_sum = jnp.sum(Mi, axis=2, keepdims=True)         # (N, n, 1)
+    cols_sum = jnp.sum(Mi, axis=1, keepdims=True)         # (N, 1, m)
+    rows_ok = jnp.min(((rows_sum == 1) | (row_iota >= n_rows)
+                       ).astype(jnp.int32), axis=1, keepdims=True)
+    cols_ok = jnp.min((cols_sum <= 1).astype(jnp.int32), axis=2,
+                      keepdims=True)
+    MG = int_dot(Mi, Gi, (((2,), (0,)), ((), ())))
+    mapped = int_dot(MG, Mi, (((2,), (2,)), ((0,), (0,))))
+    covers = jnp.min(jnp.min((mapped >= Qi[None]).astype(jnp.int32),
+                             axis=2, keepdims=True), axis=1, keepdims=True)
+    return rows_ok * cols_ok * covers
 
 
-def _finish_kernel(s_ref, f_ref, gum_ref, mask_ref, q_ref, g_ref,
-                   m_out_ref, feas_out_ref, sbar_out_ref, *,
-                   n_rows: int, gumbel_tau: float, refine_threshold: float,
-                   refine_iters: int, elite_k: int, consensus_temp: float):
+def _finish_kernel(s_ref, gum_ref, mask_ref, q_ref, g_ref,
+                   m_out_ref, feas_out_ref, *, n_rows: int,
+                   gumbel_tau: float, refine_threshold: float,
+                   refine_iters: int):
     S = s_ref[0].astype(jnp.float32)                      # (N, n, m)
-    f_final = f_ref[0].astype(jnp.float32)                # (N,)
-    mask_raw = mask_ref[0]                                # (n, m)
+    # Mosaic casts uint8 only to int32, so every 8-bit input goes via int32
+    avail_mask = (mask_ref[0].astype(jnp.int32) != 0).astype(jnp.int32)
     Qi = q_ref[0].astype(jnp.int32)
     Gi = g_ref[0].astype(jnp.int32)
-    N = S.shape[0]
-    avail_mask = (mask_raw != 0).astype(jnp.int32)        # (n, m)
-    avail0 = jnp.broadcast_to(avail_mask[None], S.shape).astype(jnp.int32)
+    avail0 = jnp.broadcast_to(avail_mask[None], S.shape)
 
     # 1. (Gumbel-perturbed) structured projection — the τ = 0 branch is
     # static, so the dummy gum block is never read when tau is off.
@@ -302,7 +290,7 @@ def _finish_kernel(s_ref, f_ref, gum_ref, mask_ref, q_ref, g_ref,
     else:
         S_proj_a = S
     M_a = _batched_structured(S_proj_a, avail0, Qi, Gi, n_rows)
-    feas_a = _batched_feasible(M_a, Qi, Gi, n_rows)
+    feas_a = _batched_feasible(M_a, Qi, Gi, n_rows)       # (N, 1, 1)
 
     # 2. greedy projection + Ullmann candidate refinement → M_b
     M_proj = _batched_greedy(S, avail0, n_rows)
@@ -318,82 +306,49 @@ def _finish_kernel(s_ref, f_ref, gum_ref, mask_ref, q_ref, g_ref,
     feas_b = _batched_feasible(M_b, Qi, Gi, n_rows)
 
     # 3. merge + feasibility verdicts
-    M_hat = jnp.where(feas_a[:, None, None], M_a, M_b)
-    feasible = feas_a | feas_b
-
-    # 4. elite consensus over the threaded-in final fitness: elite_k
-    # statically-unrolled argmax+mask rounds stand in for top_k (stable
-    # tie order matches); softmax/einsum are the literal ref ops on
-    # bitwise-identical (f_top, S_top).
-    part_iota = jax.lax.broadcasted_iota(jnp.int32, (N, 1, 1), 0)
-    pid = part_iota[:, 0, 0]                              # (N,)
-    f_work = f_final
-    f_tops, s_tops = [], []
-    for _ in range(elite_k):
-        b = jnp.argmax(f_work)
-        f_tops.append(jnp.max(f_work))
-        sel = part_iota == b
-        s_tops.append(jnp.sum(jnp.where(sel, S, 0.0), axis=0))
-        f_work = jnp.where(pid == b, _NEG, f_work)
-    f_top = jnp.stack(f_tops)                             # (k,)
-    S_top = jnp.stack(s_tops)                             # (k, n, m)
-    f_norm = (f_top - f_top[0]) / consensus_temp
-    w = jax.nn.softmax(f_norm)
-    S_bar = jnp.einsum("k,knm->nm", w, S_top)
-
-    m_out_ref[0] = M_hat
-    feas_out_ref[0] = feasible.astype(jnp.int32)
-    sbar_out_ref[0] = S_bar
+    m_out_ref[0] = jnp.where(feas_a > 0, M_a, M_b)
+    feas_out_ref[0] = jnp.maximum(feas_a, feas_b)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("n_rows", "gumbel_tau", "refine_threshold",
-                     "refine_iters", "elite_k", "consensus_temp",
-                     "interpret"))
-def epoch_finish_pallas(S, f_final, gum, mask, Q, G, *, n_rows: int,
+                     "refine_iters", "interpret"))
+def epoch_finish_pallas(S, gum, mask, Q, G, *, n_rows: int,
                         gumbel_tau: float, refine_threshold: float,
-                        refine_iters: int, elite_k: int,
-                        consensus_temp: float, interpret: bool = False):
+                        refine_iters: int, interpret: bool = False):
     """Fused batched epoch tail. ``S``: (P, N, n, m) final swarm;
-    ``f_final``: (P, N) threaded-in last-step fitness; ``gum``:
-    (P, N, n, m) pre-drawn Gumbel noise, or a (P, 1, 1, 1) dummy when
-    ``gumbel_tau == 0`` (never read — keeps HBM accounting honest);
-    ``mask``: (P, n, m); ``Q``: (P, n, n); ``G``: (P, m, m).
+    ``gum``: (P, N, n, m) pre-drawn Gumbel noise, or a (P, 1, 1, 1)
+    dummy when ``gumbel_tau == 0`` (never read — keeps HBM accounting
+    honest); ``mask``: (P, n, m); ``Q``: (P, n, n); ``G``: (P, m, m).
     ``n_rows`` is the LOGICAL query size (= n unpadded). Returns
-    ``(M_hat (P, N, n, m) int32, feasible (P, N) int32, S_bar
-    (P, n, m) f32)``; the ops layer casts to uint8/bool and crops.
+    ``(M_hat (P, N, n, m) int32, feasible (P, N) int32)``; the ops
+    layer casts to uint8/bool and crops.
     """
     P, N, n, m = S.shape
-    gn, gm = gum.shape[2], gum.shape[3]
     kernel = functools.partial(
         _finish_kernel, n_rows=n_rows, gumbel_tau=gumbel_tau,
-        refine_threshold=refine_threshold, refine_iters=refine_iters,
-        elite_k=elite_k, consensus_temp=consensus_temp)
-    m_hat, feas, s_bar = pl.pallas_call(
+        refine_threshold=refine_threshold, refine_iters=refine_iters)
+    particles = pl.BlockSpec((1, N, n, m), lambda p: (p, 0, 0, 0))
+    m_hat, feas = pl.pallas_call(
         kernel,
         grid=(P,),
         in_specs=[
-            pl.BlockSpec((1, N, n, m), lambda p: (p, 0, 0, 0)),
-            pl.BlockSpec((1, N), lambda p: (p, 0)),
-            pl.BlockSpec((1, gum.shape[1], gn, gm),
-                         lambda p: (p, 0, 0, 0)),
+            particles,
+            pl.BlockSpec((1,) + gum.shape[1:], lambda p: (p, 0, 0, 0)),
             pl.BlockSpec((1, n, m), lambda p: (p, 0, 0)),
             pl.BlockSpec((1, n, n), lambda p: (p, 0, 0)),
             pl.BlockSpec((1, m, m), lambda p: (p, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, N, n, m), lambda p: (p, 0, 0, 0)),
-            pl.BlockSpec((1, N), lambda p: (p, 0)),
-            pl.BlockSpec((1, n, m), lambda p: (p, 0, 0)),
-        ],
+        out_specs=[particles,
+                   pl.BlockSpec((1, N, 1, 1), lambda p: (p, 0, 0, 0))],
         out_shape=[
             jax.ShapeDtypeStruct((P, N, n, m), jnp.int32),
-            jax.ShapeDtypeStruct((P, N), jnp.int32),
-            jax.ShapeDtypeStruct((P, n, m), jnp.float32),
+            jax.ShapeDtypeStruct((P, N, 1, 1), jnp.int32),
         ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=vmem_limit(N * n * m * 4)),
         interpret=interpret,
-    )(S.astype(jnp.float32), f_final.astype(jnp.float32),
-      gum.astype(jnp.float32), mask, Q, G)
-    return m_hat, feas, s_bar
+    )(S.astype(jnp.float32), gum.astype(jnp.float32), mask, Q, G)
+    return m_hat, feas.reshape(P, N)

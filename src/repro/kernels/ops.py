@@ -6,9 +6,13 @@ Backends:
   "interpret" — Pallas kernels in interpret mode (CPU validation only).
   "auto"      — "pallas" on TPU else "ref".
 
-All entry points accept *logical* (unpadded) shapes; padding to multiples of
-128 (MXU tile) happens here and is provably exact for every kernel (zero
-rows/cols contribute nothing — see per-kernel notes).
+All entry points accept *logical* (unpadded) shapes; padding happens here
+and is provably exact for every kernel (zero rows/cols contribute nothing —
+see per-kernel notes). The fused prune, epoch and tail kernels pad query
+rows to a multiple of 8 (the sublane count) and target columns to a
+multiple of 128 (the lane count): a query window has at most a few dozen
+tiles, and padding its rows to 128 would multiply their VMEM footprint.
+The other kernels pad both to 128 (the MXU tile).
 """
 from __future__ import annotations
 
@@ -23,8 +27,10 @@ from repro.kernels.argmax_project import (greedy_project_pallas,
                                           masked_argmax_pallas)
 from repro.kernels.epoch_fused import (epoch_fused_pallas,
                                        epoch_inner_reference)
-from repro.kernels.finish_fused import (epoch_finish_pallas,
+from repro.kernels.finish_fused import (elite_consensus_reference,
+                                        epoch_finish_pallas,
                                         epoch_finish_reference)
+from repro.kernels.mxu import LANE, SUBLANE
 from repro.kernels.pso_fitness import (edge_fitness_pallas,
                                        edge_fitness_quantized_pallas)
 from repro.kernels.prune_fixpoint import prune_fixpoint_pallas
@@ -51,6 +57,11 @@ def _pad_to(x: jax.Array, sizes: Tuple[int, ...]) -> jax.Array:
 
 def _round_up(v: int, mult: int = MXU) -> int:
     return ((v + mult - 1) // mult) * mult
+
+
+def _fused_pad(n: int, m: int) -> Tuple[int, int]:
+    """Padded (rows, columns) of a fused kernel's (n, m) planes."""
+    return _round_up(n, SUBLANE), _round_up(m, LANE)
 
 
 # ---------------------------------------------------------------------------
@@ -132,7 +143,7 @@ def prune_fixpoint(maskb: jax.Array, Qb: jax.Array, Gb: jax.Array,
             lambda mk, Q, G: ref.prune_fixpoint_count(mk, Q, G, max_iters)
         )(maskb, Qb, Gb)
     B, n, m = maskb.shape
-    np_, mp = _round_up(n), _round_up(m)
+    np_, mp = _fused_pad(n, m)
     Mp = _pad_to(maskb, (np_, mp))
     Qp = _pad_to(Qb, (np_, np_))
     Gp = _pad_to(Gb, (mp, mp))
@@ -199,8 +210,9 @@ def epoch_fused(S, V, S_local, f_local, S_star, f_star, S_bar, mask, Q, G,
 
     Padding note: interpret mode runs UNPADDED so the fused body is
     bitwise-equal to the vmapped ref scan (zero-padding regroups f32
-    reductions by a last ulp); the compiled TPU path MXU-pads n/m —
-    exact for every integer op, allclose on the float-fitness path.
+    reductions by a last ulp); the compiled TPU path pads n to 8 rows
+    and m to 128 lanes — exact for every integer op, allclose on the
+    fitness values.
     Padded mask rows are all-zero, so they normalize to the zero
     fallback and contribute nothing to fitness.
     """
@@ -217,7 +229,7 @@ def epoch_fused(S, V, S_local, f_local, S_star, f_star, S_bar, mask, Q, G,
         return epoch_fused_pallas(S, V, S_local, f_local, S_star, f_star,
                                   S_bar, mask, Q, G, r_all, **kw)
     P, N, n, m = S.shape
-    np_, mp = _round_up(n), _round_up(m)
+    np_, mp = _fused_pad(n, m)
     s_fin, star_fin, fstar_fin, trace, f_last = epoch_fused_pallas(
         _pad_to(S, (np_, mp)), _pad_to(V, (np_, mp)),
         _pad_to(S_local, (np_, mp)), f_local,
@@ -249,20 +261,21 @@ def epoch_finish(S, f_final, gum, mask, Q, G, gumbel_tau: float,
     (P, n, n); ``G``: (P, m, m). Returns ``(M_hat (P, N, n, m) uint8,
     feasible (P, N) bool, S_bar (P, n, m) f32)``.
 
-    Padding note: interpret mode runs UNPADDED so the fused body is
-    bitwise-equal to the vmapped ref epilogue (f32 reduction grouping);
-    the compiled TPU path MXU-pads n/m — exact for the integer
-    projection/refinement/feasibility pipeline (the construction loops
-    run the logical ``n`` trips and padded mask columns never enter a
-    candidate set), allclose on the f32 consensus.
+    The kernel computes ``M_hat`` and ``feasible``; the elite consensus
+    ``S_bar`` runs beside it through the ``ref`` oracle on the unpadded
+    swarm, so it is the ``ref`` value on every backend. The compiled
+    TPU path pads n/m — exact for the integer projection / refinement /
+    feasibility pipeline (the construction loops run the logical ``n``
+    trips and padded mask columns never enter a candidate set).
     """
     backend = resolve_backend(backend)
     statics = dict(gumbel_tau=gumbel_tau,
                    refine_threshold=refine_threshold,
-                   refine_iters=refine_iters, elite_k=elite_k,
-                   consensus_temp=consensus_temp)
+                   refine_iters=refine_iters)
+    consensus = dict(elite_k=elite_k, consensus_temp=consensus_temp)
     if backend == "ref":
-        fn = functools.partial(epoch_finish_reference, **statics)
+        fn = functools.partial(epoch_finish_reference, **statics,
+                               **consensus)
         return jax.vmap(fn)(S, f_final, gum, mask, Q, G)
     P, N, n, m = S.shape
     if gum is None:
@@ -271,18 +284,19 @@ def epoch_finish(S, f_final, gum, mask, Q, G, gumbel_tau: float,
         # the kernel's HBM accounting honest
         gum = jnp.zeros((P, 1, 1, 1), jnp.float32)
     if backend == "interpret":
-        m_hat, feas, s_bar = epoch_finish_pallas(
-            S, f_final, gum, mask, Q, G, n_rows=n, interpret=True,
-            **statics)
-        return m_hat.astype(jnp.uint8), feas != 0, s_bar
-    np_, mp = _round_up(n), _round_up(m)
-    gum_p = gum if gum.shape[2] == 1 else _pad_to(gum, (np_, mp))
-    m_hat, feas, s_bar = epoch_finish_pallas(
-        _pad_to(S, (np_, mp)), f_final, gum_p,
-        _pad_to(mask, (np_, mp)), _pad_to(Q, (np_, np_)),
-        _pad_to(G, (mp, mp)), n_rows=n, interpret=False, **statics)
-    return (m_hat[:, :, :n, :m].astype(jnp.uint8), feas != 0,
-            s_bar[:, :n, :m])
+        m_hat, feas = epoch_finish_pallas(S, gum, mask, Q, G, n_rows=n,
+                                          interpret=True, **statics)
+    else:
+        np_, mp = _fused_pad(n, m)
+        gum_p = gum if gum.shape[2] == 1 else _pad_to(gum, (np_, mp))
+        m_hat, feas = epoch_finish_pallas(
+            _pad_to(S, (np_, mp)), gum_p, _pad_to(mask, (np_, mp)),
+            _pad_to(Q, (np_, np_)), _pad_to(G, (mp, mp)), n_rows=n,
+            interpret=False, **statics)
+        m_hat = m_hat[:, :, :n, :m]
+    S_bar = jax.vmap(lambda s, f: elite_consensus_reference(
+        s, f, **consensus)[0])(S, f_final)
+    return m_hat.astype(jnp.uint8), feas != 0, S_bar
 
 
 # ---------------------------------------------------------------------------

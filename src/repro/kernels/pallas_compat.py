@@ -1,11 +1,6 @@
-"""Version-compat shims for Pallas TPU symbols + capability probes.
+"""Buffer-donation capability probes.
 
-The TPU compiler-params dataclass was renamed across JAX releases
-(``TPUCompilerParams`` on 0.4.x, ``CompilerParams`` later). Kernel modules
-import ``CompilerParams`` from here instead of reaching into
-``jax.experimental.pallas.tpu`` directly.
-
-This module also hosts the **buffer-donation capability probes** the
+This module hosts the **buffer-donation capability probes** the
 service's device-resident drain pipeline gates on. Donation
 (``jax.jit(..., donate_argnums=...)``) is a documented API but its
 *effect* varies by backend and release: some platforms silently ignore
@@ -24,12 +19,6 @@ import functools
 import warnings
 
 import numpy as np
-
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None)
-if CompilerParams is None:
-    CompilerParams = pltpu.TPUCompilerParams
 
 
 def _probe_donation(call_through_export: bool) -> bool:

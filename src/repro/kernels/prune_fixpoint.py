@@ -14,8 +14,8 @@ fixpoint *in-kernel*: the mask lives in registers/VMEM for the whole loop,
 and an in-kernel convergence flag (``jnp.any(m' != m)`` as the
 ``lax.while_loop`` carry) stops the sweep the moment nothing changes — one
 ``pallas_call``, one HBM read of the mask, one write. The iteration count
-is emitted per problem (SMEM scalar) as the prune-latency observable the
-scheduler's cost accounting consumes.
+is emitted per problem (a ``(1, 1, 1)`` block) as the prune-latency
+observable the scheduler's cost accounting consumes.
 
 Grid: ``(B,)`` problems, one per step; each problem carries its OWN Q/G
 (the batched matcher prunes per-problem masks), so blocks are
@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.mxu import int_dot
 
 
 def _fused_step(mk: jax.Array, q: jax.Array, g: jax.Array) -> jax.Array:
@@ -46,17 +46,13 @@ def _fused_step(mk: jax.Array, q: jax.Array, g: jax.Array) -> jax.Array:
     ``ref.injectivity_prune`` exactly so the Pallas kernel is bitwise
     interchangeable with the jnp oracle.
     """
-    # -- refinement sweep: four matmuls on the MXU --
-    support_out = jax.lax.dot_general(
-        mk, g, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)              # M @ G^T
-    support_in = jnp.dot(mk, g, preferred_element_type=jnp.int32)
+    # -- refinement sweep: four exact 0/1 matmuls on the MXU --
+    support_out = int_dot(mk, g, (((1,), (1,)), ((), ())))   # M @ G^T
+    support_in = int_dot(mk, g, (((1,), (0,)), ((), ())))    # M @ G
     miss_out = (support_out == 0).astype(jnp.int32)
     miss_in = (support_in == 0).astype(jnp.int32)
-    viol = (jnp.dot(q, miss_out, preferred_element_type=jnp.int32)
-            + jax.lax.dot_general(
-                q, miss_in, dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32))     # Q^T @ miss_in
+    viol = (int_dot(q, miss_out, (((1,), (0,)), ((), ())))
+            + int_dot(q, miss_in, (((0,), (0,)), ((), ()))))  # Q^T @ miss_in
     mk = mk * (viol == 0).astype(jnp.int32)
     # -- injectivity propagation: row/col reductions on the VPU --
     singleton_rows = (jnp.sum(mk, axis=1, keepdims=True) == 1
@@ -87,7 +83,7 @@ def _prune_kernel(m_ref, q_ref, g_ref, o_ref, it_ref, *, max_iters: int):
     out, _, sweeps = jax.lax.while_loop(
         cond, body, (m0, jnp.bool_(True), jnp.int32(0)))
     o_ref[0] = out.astype(o_ref.dtype)
-    it_ref[0, 0] = sweeps
+    it_ref[0] = jnp.full((1, 1), sweeps, jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("max_iters", "interpret"))
@@ -109,14 +105,14 @@ def prune_fixpoint_pallas(M: jax.Array, Qb: jax.Array, Gb: jax.Array,
         ],
         out_specs=[
             pl.BlockSpec((1, n, m), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, n, m), M.dtype),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(M, Qb, Gb)
-    return out, sweeps[:, 0]
+    return out, sweeps.reshape(B)

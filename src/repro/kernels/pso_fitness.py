@@ -28,14 +28,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.mxu import int_dot, int_dot_wide, sum_last2
 
 TILE_N = 128
 
 
 def _fitness_kernel(s_blk_ref, s_full_ref, q_blk_ref, g_ref, o_ref):
     """Float path. Shapes: s_blk (1, TILE_N, m), s_full (1, n, m),
-    q_blk (TILE_N, n), g (m, m), o (1, 1)."""
+    q_blk (TILE_N, n), g (m, m), o (1, 1, 1)."""
     i = pl.program_id(1)
 
     @pl.when(i == 0)
@@ -44,8 +44,9 @@ def _fitness_kernel(s_blk_ref, s_full_ref, q_blk_ref, g_ref, o_ref):
 
     s_blk = s_blk_ref[0].astype(jnp.float32)           # (TILE_N, m)
     s_full = s_full_ref[0].astype(jnp.float32)         # (n, m)
-    g = g_ref[...].astype(jnp.float32)                 # (m, m)
-    q = q_blk_ref[...].astype(jnp.float32)             # (TILE_N, n)
+    # Mosaic casts uint8 only to int32, so 8-bit graphs go via int32
+    g = g_ref[...].astype(jnp.int32).astype(jnp.float32)      # (m, m)
+    q = q_blk_ref[...].astype(jnp.int32).astype(jnp.float32)  # (TILE_N, n)
 
     sg = jnp.dot(s_blk, g, preferred_element_type=jnp.float32)
     # (TILE_N, n) = (TILE_N, m) @ (n, m)^T
@@ -53,18 +54,18 @@ def _fitness_kernel(s_blk_ref, s_full_ref, q_blk_ref, g_ref, o_ref):
                               dimension_numbers=(((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
     r = q - sgs
-    o_ref[0, 0] += -jnp.sum(r * r)
+    o_ref[0] += -sum_last2(r * r)
 
 
 def _fitness_kernel_quantized(s_blk_ref, s_full_ref, q_blk_ref, g_ref, o_ref,
                               *, scale: int):
     """Fixed-point path: S is uint8 (≈ S*scale), Q/G are {0,1} uint8.
 
-    First matmul uses the int8 MXU path (uint8 × uint8 → int32 accumulate);
-    the second contracts the int32 partials against uint8 S (int32
-    accumulate). The squared-residual reduction accumulates in f32 — the
-    role of the hardware's wide accumulator tree. Residual is in units of
-    1/scale², so fitness ordering matches the float kernel.
+    Both matmuls are exact integer contractions on the MXU
+    (``mxu.int_dot``: 8-bit × 0/1, then the wide partials split into
+    bytes against 8-bit S). The squared-residual reduction accumulates in
+    f32 — the role of the hardware's wide accumulator tree. Residual is
+    in units of 1/scale², so fitness ordering matches the float kernel.
     """
     i = pl.program_id(1)
 
@@ -77,12 +78,10 @@ def _fitness_kernel_quantized(s_blk_ref, s_full_ref, q_blk_ref, g_ref, o_ref,
     g = g_ref[...].astype(jnp.int32)                   # (m, m)
     q = q_blk_ref[...].astype(jnp.int32)               # (TILE_N, n)
 
-    sg = jnp.dot(s_blk, g, preferred_element_type=jnp.int32)
-    sgs = jax.lax.dot_general(sg, s_full,
-                              dimension_numbers=(((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.int32)
+    sg = int_dot(s_blk, g, (((1,), (0,)), ((), ())))
+    sgs = int_dot_wide(sg, s_full, (((1,), (1,)), ((), ())))
     r = (q * (scale * scale) - sgs).astype(jnp.float32)
-    o_ref[0, 0] += -jnp.sum(r * r)
+    o_ref[0] += -sum_last2(r * r)
 
 
 def _grid_specs(B: int, n: int, m: int, s_dtype, q_dtype):
@@ -94,7 +93,7 @@ def _grid_specs(B: int, n: int, m: int, s_dtype, q_dtype):
         pl.BlockSpec((TILE_N, n), lambda b, i: (i, 0)),         # Q row-block
         pl.BlockSpec((m, m), lambda b, i: (0, 0)),              # G
     ]
-    out_specs = pl.BlockSpec((1, 1), lambda b, i: (b, 0))
+    out_specs = pl.BlockSpec((1, 1, 1), lambda b, i: (b, 0, 0))
     return grid, in_specs, out_specs
 
 
@@ -113,12 +112,12 @@ def edge_fitness_pallas(S: jax.Array, Q: jax.Array, G: jax.Array,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(S, S, Q, G)
-    return out[:, 0]
+    return out.reshape(B)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -134,9 +133,9 @@ def edge_fitness_quantized_pallas(S_q: jax.Array, Q: jax.Array, G: jax.Array,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(S_q, S_q, Q, G)
-    return out[:, 0]
+    return out.reshape(B)

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 TILE_N = 128
 EPS = 1e-9
@@ -102,7 +101,7 @@ def pso_update_pallas(S, V, S_local, S_star, S_bar, mask, r,
             jax.ShapeDtypeStruct((B, n, m), jnp.float32),
             jax.ShapeDtypeStruct((B, n, m), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(r, S, V, S_local, S_star, S_bar, mask)

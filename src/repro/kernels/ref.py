@@ -13,6 +13,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.mxu import sum_last2
+
 EPS = 1e-9
 
 
@@ -28,7 +30,8 @@ def edge_fitness(S: jax.Array, Q: jax.Array, G: jax.Array) -> jax.Array:
     SG = S @ Gf                      # (n, m)
     SGS = SG @ S.T                   # (n, n)
     resid = Qf - SGS
-    return -jnp.sum(resid * resid)
+    # row sums first, then their sum: the order the fused kernels reduce in
+    return -sum_last2(resid * resid)[0, 0]
 
 
 def edge_fitness_quantized(S_q: jax.Array, Q: jax.Array, G: jax.Array,
@@ -50,7 +53,7 @@ def edge_fitness_quantized(S_q: jax.Array, Q: jax.Array, G: jax.Array,
     SG = S_i @ G_i                   # int32 (n, m)
     SGS = SG @ S_i.T                 # int32 (n, n), units of 1/scale^2
     resid = (Q_i * (scale * scale) - SGS).astype(jnp.float32)
-    return -jnp.sum(resid * resid)
+    return -sum_last2(resid * resid)[0, 0]
 
 
 # ---------------------------------------------------------------------------

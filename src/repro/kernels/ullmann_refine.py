@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _refine_kernel(m_ref, q_ref, g_ref, o_ref):
     m_in = m_ref[0].astype(jnp.int32)                  # (n, m)
@@ -68,7 +66,7 @@ def ullmann_refine_step_pallas(M: jax.Array, Q: jax.Array, G: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, n, m), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, n, m), M.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(M, Q, G)

@@ -24,41 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.tree_util import DictKey, SequenceKey, tree_flatten_with_path
 
 
-def get_shard_map():
-    """Version-compat accessor for ``shard_map``.
-
-    Returns a callable ``shard_map(f, mesh=..., in_specs=..., out_specs=...)``
-    with replication checking disabled, across JAX versions:
-      * newer JAX exposes ``jax.shard_map`` (``check_vma=`` kwarg),
-      * 0.4.x only has ``jax.experimental.shard_map.shard_map``
-        (``check_rep=`` kwarg).
-
-    Every shard_map call site in this repo (and in test subprocess
-    snippets) must go through here rather than touching ``jax.shard_map``
-    directly.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm  # noqa: N813
-        kwarg_prefs = ({"check_rep": False}, {"check_vma": False})
-    else:
-        kwarg_prefs = ({"check_vma": False}, {"check_rep": False})
-
-    def wrap(f, *, mesh, in_specs, out_specs):
-        # the check-disable kwarg was renamed across versions; try both
-        # names before giving it up (the matcher's fused collectives rely
-        # on the replication checker being off)
-        for kw in kwarg_prefs:
-            try:
-                return sm(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-            except TypeError:
-                continue
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-    return wrap
-
-
 def mesh_axes(mesh: Mesh, profile: str = "2d"):
     """profile "2d": fsdp over (pod, data) + tensor over "model".
     profile "fsdp_only": every axis joins the FSDP/batch group and tensor

@@ -82,7 +82,7 @@ SEAM_FUNCTIONS = [
     ("repro.kernels.backend", "register_backend"),
     ("repro.kernels.backend", "resolve_backend_name"),
     ("repro.kernels.backend", "config_digest"),
-    ("repro.core.persist", "enable_jax_compilation_cache"),
+    ("repro.core.persist", "enable_compilation_cache"),
     ("repro.sched.metrics", "warm_restart_stats"),
     ("repro.sched.tasks", "make_restart_scenario"),
 ]

@@ -157,3 +157,22 @@ def test_quantized_fitness_ordering_matches_float():
     for a, b in zip(order_f[:-1], order_f[1:]):
         if f_float[b] - f_float[a] > 1.0:  # > uint8 quantization noise band
             assert f_scaled[b] > f_scaled[a]
+
+
+@pytest.mark.parametrize("terms", [16, 144, 256])
+def test_int_dot_wide_is_exact_integer_matmul(terms):
+    """The MXU form of the quantized fitness's S G Sᵀ: 8-bit S against
+    partial sums up to 255·terms, bitwise the int32 product; longer
+    contractions than it is exact for are refused."""
+    from repro.kernels.mxu import int_dot, int_dot_wide
+    k1, k2 = jax.random.split(jax.random.PRNGKey(terms))
+    S = jax.random.randint(k1, (24, terms), 0, 256, jnp.int32)
+    G = jax.random.bernoulli(k2, 0.5, (terms, terms)).astype(jnp.int32)
+    dims = (((1,), (0,)), ((), ()))
+    SG = int_dot(S, G, dims)
+    np.testing.assert_array_equal(SG, S @ G)
+    nt = (((1,), (1,)), ((), ()))
+    np.testing.assert_array_equal(int_dot_wide(SG, S, nt), SG @ S.T)
+    with pytest.raises(ValueError):
+        int_dot_wide(jnp.zeros((8, 259), jnp.int32),
+                      jnp.zeros((8, 259), jnp.int32), nt)
