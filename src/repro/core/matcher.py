@@ -172,7 +172,9 @@ def build_distributed_match(Q_shape: Tuple[int, int], mesh: Mesh,
     ``carry0`` is a replicated ``(S_star, f_star, S_bar)`` warm-start (use
     ``pso.default_carry(mask)`` for a cold start). The result pytree
     mirrors ``pso.match`` with a leading shard axis on the per-particle
-    outputs.
+    outputs. The jitted function is named ``immsched_swarm``, as the
+    service's single-device one is, so its XLA module reads
+    ``jit_immsched_swarm``.
 
     The returned executable is tagged ``aot_exportable = False``: a
     ``jax.export``-serialized shard_map program pins the exporting
@@ -239,15 +241,21 @@ def build_distributed_match(Q_shape: Tuple[int, int], mesh: Mesh,
 
     fn = jax.shard_map(local_match, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    return _mark_mesh_executable(jax.jit(fn))
+    return _mesh_jit("immsched_swarm", fn)
 
 
-def _mark_mesh_executable(fn):
-    """Tag a mesh-bound executable so the AOT persistence layer skips
+def _mesh_jit(name: str, fn):
+    """``jax.jit`` of ``fn`` under ``name`` (its XLA module reads
+    ``jit_<name>``), tagged so the AOT persistence layer skips
     ``jax.export`` for it (the serialized program would pin this
     process's device count/topology); see ``build_distributed_match``."""
-    fn.aot_exportable = False
-    return fn
+    def named(*args):
+        return fn(*args)
+
+    named.__name__ = named.__qualname__ = name
+    jitted = jax.jit(named)
+    jitted.aot_exportable = False
+    return jitted
 
 
 def build_distributed_match_batch(Q_shape: Tuple[int, int], mesh: Mesh,
@@ -269,7 +277,8 @@ def build_distributed_match_batch(Q_shape: Tuple[int, int], mesh: Mesh,
         (unrolled — B is static), stacking results on the problem axis.
 
     Output layout matches ``pso.match_batch`` (problem axis after the
-    epoch axis on per-epoch leaves, leading elsewhere).
+    epoch axis on per-epoch leaves, leading elsewhere). Either way the
+    jitted function is named ``immsched_swarm_batch``.
     """
     axis_names = tuple(axis_names)
     num_shards = int(np.prod([mesh.shape[a] for a in axis_names]))
@@ -289,7 +298,7 @@ def build_distributed_match_batch(Q_shape: Tuple[int, int], mesh: Mesh,
             carry_feasible=shard_b, prune_sweeps=shard_b)
         fn = jax.shard_map(local_match, mesh=mesh, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)
-        return _mark_mesh_executable(jax.jit(fn))
+        return _mesh_jit("immsched_swarm_batch", fn)
 
     per_problem = build_distributed_match(Q_shape, mesh, cfg, axis_names)
     per_epoch = ("mappings", "feasible", "fitness", "f_star_trace")
@@ -304,7 +313,7 @@ def build_distributed_match_batch(Q_shape: Tuple[int, int], mesh: Mesh,
                              axis=1 if k in per_epoch else 0)
                 for k in outs_list[0]}
 
-    return _mark_mesh_executable(jax.jit(fn))
+    return _mesh_jit("immsched_swarm_batch", fn)
 
 
 def build_distributed_revalidate_batch(Q_shape: Tuple[int, int], mesh: Mesh,
@@ -324,6 +333,8 @@ def build_distributed_revalidate_batch(Q_shape: Tuple[int, int], mesh: Mesh,
         (tiny) batch — one projection per problem is far below the cost of
         re-sharding, and the replicated outputs keep the calling
         convention identical.
+
+    The jitted function is named ``immsched_revalidate``.
     """
     axis_names = tuple(axis_names)
     num_shards = int(np.prod([mesh.shape[a] for a in axis_names]))
@@ -344,7 +355,7 @@ def build_distributed_revalidate_batch(Q_shape: Tuple[int, int], mesh: Mesh,
                          f_carry=P())
     fn = jax.shard_map(local_reval, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    return _mark_mesh_executable(jax.jit(fn))
+    return _mesh_jit("immsched_revalidate", fn)
 
 
 class IMMSchedMatcher:

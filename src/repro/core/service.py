@@ -51,9 +51,24 @@ that joins the compile-cache key; pad slots are filled with a *trivial
 pre-finished problem* whose carry validates in epoch 0, so padding never
 re-burns a real problem's epoch budget (its only cost is the slot width).
 
-Per-tier statistics (launches / problems checked / hits / wall time) are
-exported via ``stats`` / ``stats_dict()`` and surfaced by
-``sched.metrics`` through ``SimResult.matcher_stats``.
+Per-tier statistics (launches / problems checked / hits) are exported
+via ``stats`` / ``stats_dict()`` and surfaced by ``sched.metrics``
+through ``SimResult.matcher_stats``.
+
+**Tracing.** The served path writes spans into the profiler's trace with
+``jax.profiler.TraceAnnotation``; each is a no-op (1–2 µs) unless a
+trace is active. ``immsched.drain`` covers one front-end drain round
+(args ``drain``, ``reason``, ``requests``); inside it
+``immsched.prepare`` covers each request's ``_prepare`` (``rid``),
+``immsched.dispatch`` each tier launch's build and enqueue (``tier``,
+``B``, ``bclass``), ``immsched.fetch`` each blocking ``_sync_fetch`` and
+``immsched.apply`` each launch's result handling (``tier``). Every span
+of a front-end drain carries its ``drain`` number, which the front end
+passes to ``submit`` and each request carries to the launches that
+serve it. The tier programs are
+jitted as ``immsched_swarm``, ``immsched_swarm_batch`` and
+``immsched_revalidate``, so their XLA modules read ``jit_immsched_*``
+(an executable restored from the AOT cache reads ``jit_call_exported``).
 """
 from __future__ import annotations
 
@@ -69,6 +84,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.accel.target_graph import signature_bits
 from repro.checkpoint.manager import CheckpointManager
@@ -87,6 +103,13 @@ from repro.kernels import pallas_compat
 # process-global latch: the export-drops-donation degradation warning
 # fires at most once however many services a process builds
 _DONATION_EXPORT_WARNED: List[bool] = []
+
+
+def _span(name: str, **args) -> TraceAnnotation:
+    """Profiler span ``immsched.<name>`` with the ``args`` that are set;
+    a no-op unless a trace is active."""
+    return TraceAnnotation("immsched." + name,
+                           **{k: v for k, v in args.items() if v is not None})
 
 
 def _round_up(v: int, mult: int) -> int:
@@ -112,7 +135,6 @@ class TierStats:
     launches: int = 0                # jit dispatches this tier issued
     checked: int = 0                 # real problems examined
     hits: int = 0                    # requests served by this tier
-    wall_s: float = 0.0              # wall time spent in this tier
 
     @property
     def hit_rate(self) -> float:
@@ -278,6 +300,7 @@ class _PendingRequest:
     engine_sig: Optional[bytes] = None   # free-engine bitmask (Tier-1 key)
     qdigest: str = ""                    # query-content digest (Tier-1 key)
     cdigest: str = ""                    # full-content digest (Tier-0 key)
+    drain: Optional[int] = None          # front-end drain number (span tag)
 
 
 @dataclasses.dataclass(eq=False)
@@ -315,7 +338,7 @@ class _LaunchRecord:
     carries: Optional[List] = None   # reval: per-item input carries
     padded: Optional[List] = None    # swarm: padded request list
     miss_sink: Optional[List] = None # reval: where misses are appended
-    t0: float = 0.0                  # dispatch timestamp
+    drain: Optional[int] = None      # front-end drain number (span tag)
 
 
 class CarryStore:
@@ -997,32 +1020,39 @@ class MatcherService:
         return self._cache_put(cache_key, self._count_first_call(build()))
 
     def _executable(self, bucket: Tuple[int, int]):
-        """Single-problem swarm executable for one shape bucket."""
+        """Single-problem swarm executable for one shape bucket, jitted
+        as ``immsched_swarm``: its XLA module reads
+        ``jit_immsched_swarm``, or ``jit_call_exported`` once restored
+        from the AOT cache."""
         def build():
             if self.mesh is None:
                 cfg = self.cfg
 
-                def fn(key, Q, G, mask, carry0, _cfg=cfg):
+                def immsched_swarm(key, Q, G, mask, carry0, _cfg=cfg):
                     return pso._match_body(key, Q, G, mask, _cfg, carry0)
 
-                return jax.jit(fn)
+                return jax.jit(immsched_swarm)
             return build_distributed_match(bucket, self.mesh, self.cfg,
                                            self.axis_names)
 
         return self._resolve_executable(bucket, "match", bucket, 1, build)
 
     def _executable_batch(self, bucket: Tuple[int, int], bclass: int):
-        """One swarm executable per (shape bucket, padded batch class)."""
+        """One swarm executable per (shape bucket, padded batch class),
+        jitted as ``immsched_swarm_batch`` (module
+        ``jit_immsched_swarm_batch``; ``jit_call_exported`` once
+        restored from the AOT cache)."""
         def build():
             if self.mesh is None:
                 cfg = self.cfg
 
-                def fn(keys, Qb, Gb, maskb, carry0, _cfg=cfg):
+                def immsched_swarm_batch(keys, Qb, Gb, maskb, carry0,
+                                         _cfg=cfg):
                     return pso._match_batch_body(keys, Qb, Gb, maskb, _cfg,
                                                  carry0)
 
-                return jax.jit(
-                    fn, donate_argnums=self._donate_argnums("batch"))
+                return jax.jit(immsched_swarm_batch,
+                               donate_argnums=self._donate_argnums("batch"))
             return build_distributed_match_batch(bucket, self.mesh,
                                                  self.cfg, self.axis_names,
                                                  bclass)
@@ -1031,17 +1061,19 @@ class MatcherService:
                                         bucket, bclass, build)
 
     def _executable_reval(self, bucket: Tuple[int, int], bclass: int):
-        """Tier-0/1 revalidation executable (no epochs, no keys)."""
+        """Tier-0/1 revalidation executable (no epochs, no keys), jitted
+        as ``immsched_revalidate`` (module ``jit_immsched_revalidate``;
+        ``jit_call_exported`` once restored from the AOT cache)."""
         def build():
             if self.mesh is None:
                 cfg = self.cfg
 
-                def fn(Qb, Gb, maskb, carry0, _cfg=cfg):
+                def immsched_revalidate(Qb, Gb, maskb, carry0, _cfg=cfg):
                     return pso._revalidate_batch_body(Qb, Gb, maskb, _cfg,
                                                       carry0)
 
-                return jax.jit(
-                    fn, donate_argnums=self._donate_argnums("reval"))
+                return jax.jit(immsched_revalidate,
+                               donate_argnums=self._donate_argnums("reval"))
             return build_distributed_revalidate_batch(
                 bucket, self.mesh, self.cfg, self.axis_names, bclass)
 
@@ -1273,37 +1305,43 @@ class MatcherService:
     # -- matching ----------------------------------------------------------
 
     def _prepare(self, query: Graph, target: Graph, key, workload_key,
-                 engine_sig: Optional[bytes] = None) -> _PendingRequest:
+                 engine_sig: Optional[bytes] = None,
+                 rid: Optional[int] = None,
+                 drain: Optional[int] = None) -> _PendingRequest:
         """Relabel, bucket and pad a problem on the host — the jit call
         uploads Qp/Gp/maskp once; no device→host→device round trip.
+        Runs under an ``immsched.prepare`` span carrying ``rid`` and
+        ``drain``, the front end's request id and drain number, when
+        given; the request keeps ``drain`` for its launches' spans.
 
         ``engine_sig`` (the free-engine bitmask, see
         ``accel.target_graph.free_engine_signature``) keys the similarity
         store; when omitted it is recovered from a ``(name, sig)``-style
         ``workload_key`` whose last element is bytes — the scheduler's
         existing naming convention."""
-        if key is None:
-            key = jax.random.PRNGKey(0)
-        if engine_sig is None and isinstance(workload_key, tuple) \
-                and workload_key and isinstance(workload_key[-1], bytes):
-            engine_sig = workload_key[-1]
-        q, order = topological_relabel(query)
-        n, m = q.n, target.n
-        mask = compatibility_mask(q, target)
-        bucket = shape_bucket(n, m, self.n_multiple, self.m_multiple)
-        Qp, Gp, maskp = pad_problem(q.adj, target.adj, mask, *bucket)
-        # one hashing pass yields both keys: the query-only digest (the
-        # similarity key) is a prefix state of the full content digest
-        # (the exact warm key)
-        h = hashlib.sha1(np.ascontiguousarray(Qp).tobytes())
-        qdigest = h.hexdigest()
-        h.update(np.ascontiguousarray(Gp).tobytes())
-        h.update(np.ascontiguousarray(maskp).tobytes())
-        return _PendingRequest(key=key, workload_key=workload_key,
-                               order=order, crop=(n, m), bucket=bucket,
-                               Qp=Qp, Gp=Gp, maskp=maskp,
-                               engine_sig=engine_sig, qdigest=qdigest,
-                               cdigest=h.hexdigest())
+        with _span("prepare", rid=rid, drain=drain):
+            if key is None:
+                key = jax.random.PRNGKey(0)
+            if engine_sig is None and isinstance(workload_key, tuple) \
+                    and workload_key and isinstance(workload_key[-1], bytes):
+                engine_sig = workload_key[-1]
+            q, order = topological_relabel(query)
+            n, m = q.n, target.n
+            mask = compatibility_mask(q, target)
+            bucket = shape_bucket(n, m, self.n_multiple, self.m_multiple)
+            Qp, Gp, maskp = pad_problem(q.adj, target.adj, mask, *bucket)
+            # one hashing pass yields both keys: the query-only digest (the
+            # similarity key) is a prefix state of the full content digest
+            # (the exact warm key)
+            h = hashlib.sha1(np.ascontiguousarray(Qp).tobytes())
+            qdigest = h.hexdigest()
+            h.update(np.ascontiguousarray(Gp).tobytes())
+            h.update(np.ascontiguousarray(maskp).tobytes())
+            return _PendingRequest(key=key, workload_key=workload_key,
+                                   order=order, crop=(n, m), bucket=bucket,
+                                   Qp=Qp, Gp=Gp, maskp=maskp,
+                                   engine_sig=engine_sig, qdigest=qdigest,
+                                   cdigest=h.hexdigest(), drain=drain)
 
     def _note_prune(self, problems: int, sweeps: int) -> None:
         """Account the fused pre-prune work a launch reported (the
@@ -1320,7 +1358,7 @@ class MatcherService:
 
     # -- device residency --------------------------------------------------
 
-    def _sync_fetch(self, tree):
+    def _sync_fetch(self, tree, drain: Optional[int] = None):
         """THE blocking device→host transfer of the drain pipeline.
 
         Fetches a whole pytree (typically every pending launch's outputs)
@@ -1328,11 +1366,13 @@ class MatcherService:
         census: ``host_syncs`` (count), ``host_bytes_transferred``
         (payload) and ``host_sync_wall_s`` (time spent blocked). Every
         result-consuming path routes through here, so the counters ARE
-        the sync budget the transfer-guard test pins."""
-        t0 = time.perf_counter()
-        host = jax.device_get(tree)
-        self.stats.host_syncs += 1
-        self.stats.host_sync_wall_s += time.perf_counter() - t0
+        the sync budget the transfer-guard test pins. ``drain`` tags its
+        ``immsched.fetch`` span."""
+        with _span("fetch", drain=drain):
+            t0 = time.perf_counter()
+            host = jax.device_get(tree)
+            self.stats.host_syncs += 1
+            self.stats.host_sync_wall_s += time.perf_counter() - t0
         self.stats.host_bytes_transferred += int(sum(
             getattr(leaf, "nbytes", 0)
             for leaf in jax.tree_util.tree_leaves(host)))
@@ -1512,11 +1552,15 @@ class MatcherService:
 
     def submit(self, query: Graph, target: Graph,
                key: Optional[jax.Array] = None, workload_key=None,
-               engine_sig: Optional[bytes] = None) -> int:
+               engine_sig: Optional[bytes] = None,
+               rid: Optional[int] = None,
+               drain: Optional[int] = None) -> int:
         """Queue a problem for the next ``drain``; returns its ticket
-        index into the results list ``drain`` will return."""
+        index into the results list ``drain`` will return. ``rid`` and
+        ``drain`` (the front end's request id and drain number) tag its
+        spans."""
         self._pending.append(self._prepare(query, target, key, workload_key,
-                                           engine_sig))
+                                           engine_sig, rid, drain))
         return len(self._pending) - 1
 
     @property
@@ -1712,7 +1756,8 @@ class MatcherService:
         ordering)."""
         if not recs:
             return
-        hosts = self._sync_fetch([self._fetch_tree(rec) for rec in recs])
+        hosts = self._sync_fetch([self._fetch_tree(rec) for rec in recs],
+                                 recs[0].drain)
         for rec, host in zip(recs, hosts):
             if rec.kind == "reval":
                 self._apply_revalidate(rec, host)
@@ -1748,7 +1793,8 @@ class MatcherService:
         misses: List[_PipelineItem] = []
         rec = self._dispatch_revalidate(bucket, items, carries, tier,
                                         miss_sink=misses)
-        self._apply_revalidate(rec, self._sync_fetch(self._fetch_tree(rec)))
+        self._apply_revalidate(rec, self._sync_fetch(self._fetch_tree(rec),
+                                                     rec.drain))
         return misses
 
     def _dispatch_revalidate(self, bucket, items: List[_PipelineItem],
@@ -1758,104 +1804,107 @@ class MatcherService:
         the batch, stack the carries device-side, dispatch. The returned
         record resolves via ``_apply_revalidate`` once its outputs are
         fetched."""
-        t0 = time.perf_counter()
         B = len(items)
         bclass = self._batch_class(B)
         tstats = self.stats.tier0 if tier == 0 else self.stats.tier1
+        drain = items[0].req.drain
 
-        hits_before = self.stats.compile_cache_hits
-        fn = self._executable_reval(bucket, bclass)
-        compile_hit = self.stats.compile_cache_hits > hits_before
+        with _span("dispatch", tier=tier, B=B, bclass=bclass, drain=drain):
+            hits_before = self.stats.compile_cache_hits
+            fn = self._executable_reval(bucket, bclass)
+            compile_hit = self.stats.compile_cache_hits > hits_before
 
-        reqs = [it.req for it in items]
-        stored = list(carries)
-        padded, carries = list(reqs), list(carries)
-        if bclass > B:
-            pad_req, pad_carry = self._pad_slot(bucket, reqs[0], carries[0])
-            padded += [pad_req] * (bclass - B)
-            carries += [pad_carry] * (bclass - B)
-        Qb = np.stack([r.Qp for r in padded])
-        Gb = np.stack([r.Gp for r in padded])
-        maskb = np.stack([r.maskp for r in padded])
-        carry0 = self._stack_carries(carries)
-        if self.mesh is None and self._donate_argnums("reval"):
-            self.stats.donated_launches += 1
+            reqs = [it.req for it in items]
+            stored = list(carries)
+            padded, carries = list(reqs), list(carries)
+            if bclass > B:
+                pad_req, pad_carry = self._pad_slot(bucket, reqs[0],
+                                                    carries[0])
+                padded += [pad_req] * (bclass - B)
+                carries += [pad_carry] * (bclass - B)
+            Qb = np.stack([r.Qp for r in padded])
+            Gb = np.stack([r.Gp for r in padded])
+            maskb = np.stack([r.maskp for r in padded])
+            carry0 = self._stack_carries(carries)
+            if self.mesh is None and self._donate_argnums("reval"):
+                self.stats.donated_launches += 1
 
-        outs = fn(Qb, Gb, maskb, carry0)
-        tstats.launches += 1
-        tstats.checked += B
-        return _LaunchRecord(kind="reval", bucket=bucket, items=items,
-                             tier=tier, B=B, bclass=bclass,
-                             compile_hit=compile_hit, outs=outs,
-                             carries=stored, miss_sink=miss_sink, t0=t0)
+            outs = fn(Qb, Gb, maskb, carry0)
+            tstats.launches += 1
+            tstats.checked += B
+            return _LaunchRecord(kind="reval", bucket=bucket, items=items,
+                                 tier=tier, B=B, bclass=bclass,
+                                 compile_hit=compile_hit, outs=outs,
+                                 carries=stored, miss_sink=miss_sink,
+                                 drain=drain)
 
     def _apply_revalidate(self, rec: _LaunchRecord, host: dict) -> None:
         """Consume one fetched revalidation launch: attach hit results,
         append misses to the record's sink (with their Tier-2 seeds),
         refresh stores. All array reads come from ``host`` or stay on
         device — this path never blocks."""
-        tier, B, items = rec.tier, rec.B, rec.items
-        bucket, carries = rec.bucket, rec.carries
-        tstats = self.stats.tier0 if tier == 0 else self.stats.tier1
-        # Tier 0 re-validates this problem's own carry (carried-f* gate);
-        # Tier 1 additionally requires the rebased projection to clear the
-        # fitness bound on THIS problem (stored f* isn't transferable)
-        ok = np.asarray(host["ok" if tier == 0 else "ok_rebase"])
-        maps = np.asarray(host["mapping"])
-        # leaves outside this tier's _fetch_tree subset stay on device
-        fits = host.get("fitness")
-        S_rb = host.get("S_star")
-        S_bar_rb = host.get("S_bar")
-        f_carry = host.get("f_carry")
-        sweeps = np.asarray(host["prune_sweeps"]).reshape(-1)
-        self._note_prune(B, int(sweeps[:B].sum()))
-        on_device = self.mesh is None
-        done = time.perf_counter()
+        with _span("apply", tier=rec.tier, drain=rec.drain):
+            tier, B, items = rec.tier, rec.B, rec.items
+            bucket, carries = rec.bucket, rec.carries
+            tstats = self.stats.tier0 if tier == 0 else self.stats.tier1
+            # Tier 0 re-validates this problem's own carry (carried-f* gate);
+            # Tier 1 additionally requires the rebased projection to clear the
+            # fitness bound on THIS problem (stored f* isn't transferable)
+            ok = np.asarray(host["ok" if tier == 0 else "ok_rebase"])
+            maps = np.asarray(host["mapping"])
+            # leaves outside this tier's _fetch_tree subset stay on device
+            fits = host.get("fitness")
+            S_rb = host.get("S_star")
+            S_bar_rb = host.get("S_bar")
+            f_carry = host.get("f_carry")
+            sweeps = np.asarray(host["prune_sweeps"]).reshape(-1)
+            self._note_prune(B, int(sweeps[:B].sum()))
+            on_device = self.mesh is None
+            done = time.perf_counter()
 
-        tstats.wall_s += done - rec.t0
-        for j, it in enumerate(items):
-            it.latency_s = done - it.t0
-            if not ok[j]:
-                if tier == 1:
-                    # rebased controller state seeds the Tier-2 swarm;
-                    # keep it device-resident (slices of the launch
-                    # outputs) so the swarm stack never touches host
-                    if on_device:
-                        it.seed = (rec.outs["S_star"][j],
-                                   np.float32(-np.inf),
-                                   rec.outs["S_bar"][j])
-                    else:
-                        it.seed = (S_rb[j], np.float32(-np.inf),
-                                   S_bar_rb[j])
-                rec.miss_sink.append(it)
-                continue
-            tstats.hits += 1
-            self.stats.carry_fastpath_hits += 1
-            self.stats.found += 1
-            if tier == 0:
-                # the stored carry revalidated: it stays in the store
-                # untouched; its f* comes from the output echo, not a
-                # per-item device read, and the result's carry is a lazy
-                # view — no pool slicing unless the caller looks at it
-                carry = (_LazyCarry(carries[j])
-                         if isinstance(carries[j], _CarryHandle)
-                         else self._carry_tuple(carries[j]))
-                f_res = float(f_carry[j])
-            else:
-                carry = (S_rb[j], fits[j], S_bar_rb[j])
-                f_res = float(fits[j])
-                if self.warm_start:
-                    stored = self._pool.put(
-                        (rec.outs["S_star"][j], rec.outs["fitness"][j],
-                         rec.outs["S_bar"][j])) if on_device else carry
-                    self._put_carry(it.warm_key, stored)
-                    if it.req.engine_sig is not None:
-                        self._carries.put_similar(it.req.qdigest, bucket,
-                                                  it.req.engine_sig,
-                                                  stored)
-            it.result = self._revalidated_result(
-                it, maps[j], f_res, carry, tier=tier, batch=B,
-                compile_hit=rec.compile_hit, prune_sweeps=int(sweeps[j]))
+            for j, it in enumerate(items):
+                it.latency_s = done - it.t0
+                if not ok[j]:
+                    if tier == 1:
+                        # rebased controller state seeds the Tier-2 swarm;
+                        # keep it device-resident (slices of the launch
+                        # outputs) so the swarm stack never touches host
+                        if on_device:
+                            it.seed = (rec.outs["S_star"][j],
+                                       np.float32(-np.inf),
+                                       rec.outs["S_bar"][j])
+                        else:
+                            it.seed = (S_rb[j], np.float32(-np.inf),
+                                       S_bar_rb[j])
+                    rec.miss_sink.append(it)
+                    continue
+                tstats.hits += 1
+                self.stats.carry_fastpath_hits += 1
+                self.stats.found += 1
+                if tier == 0:
+                    # the stored carry revalidated: it stays in the store
+                    # untouched; its f* comes from the output echo, not a
+                    # per-item device read, and the result's carry is a lazy
+                    # view — no pool slicing unless the caller looks at it
+                    carry = (_LazyCarry(carries[j])
+                             if isinstance(carries[j], _CarryHandle)
+                             else self._carry_tuple(carries[j]))
+                    f_res = float(f_carry[j])
+                else:
+                    carry = (S_rb[j], fits[j], S_bar_rb[j])
+                    f_res = float(fits[j])
+                    if self.warm_start:
+                        stored = self._pool.put(
+                            (rec.outs["S_star"][j], rec.outs["fitness"][j],
+                             rec.outs["S_bar"][j])) if on_device else carry
+                        self._put_carry(it.warm_key, stored)
+                        if it.req.engine_sig is not None:
+                            self._carries.put_similar(it.req.qdigest, bucket,
+                                                      it.req.engine_sig,
+                                                      stored)
+                it.result = self._revalidated_result(
+                    it, maps[j], f_res, carry, tier=tier, batch=B,
+                    compile_hit=rec.compile_hit, prune_sweeps=int(sweeps[j]))
 
     def _revalidated_result(self, item: _PipelineItem, M_c: np.ndarray,
                             f_res: float, carry, *, tier: int, batch: int,
@@ -1926,106 +1975,109 @@ class MatcherService:
         items: dispatch, then a blocking fetch of just this launch's
         outputs (the one-sync-per-launch baseline arm)."""
         rec = self._dispatch_swarm(bucket, items)
-        self._apply_swarm(rec, self._sync_fetch(rec.outs))
+        self._apply_swarm(rec, self._sync_fetch(rec.outs, rec.drain))
 
     def _dispatch_swarm(self, bucket, items: List[_PipelineItem]
                         ) -> _LaunchRecord:
         """Enqueue one Tier-2 swarm launch (no host sync) over items
         whose carries are already resolved: failed exact carry, rebased
         neighbour seed, or the cold prior."""
-        t0 = time.perf_counter()
         B = len(items)
         bclass = self._batch_class(B)
+        drain = items[0].req.drain
 
-        hits_before = self.stats.compile_cache_hits
-        fn = self._executable_batch(bucket, bclass)
-        compile_hit = self.stats.compile_cache_hits > hits_before
+        with _span("dispatch", tier=2, B=B, bclass=bclass, drain=drain):
+            hits_before = self.stats.compile_cache_hits
+            fn = self._executable_batch(bucket, bclass)
+            compile_hit = self.stats.compile_cache_hits > hits_before
 
-        reqs = [it.req for it in items]
-        carries = []
-        for it in items:
-            if it.carry is not None:
-                carries.append(it.carry)
-            elif it.seed is not None:
-                carries.append(it.seed)
+            reqs = [it.req for it in items]
+            carries = []
+            for it in items:
+                if it.carry is not None:
+                    carries.append(it.carry)
+                elif it.seed is not None:
+                    carries.append(it.seed)
+                else:
+                    carries.append(
+                        pso.default_carry(jnp.asarray(it.req.maskp)))
+
+            pad = bclass - B
+            padded = list(reqs)
+            if pad:
+                pad_req, pad_carry = self._pad_slot(bucket, reqs[0],
+                                                    carries[0])
+                padded += [pad_req] * pad
+                carries = carries + [pad_carry] * pad
+                if pad_req is not reqs[0] and self.cfg.early_exit \
+                        and self.cfg.carry_fastpath:
+                    self.stats.pad_slots_frozen += pad
+            if self.mesh is None:
+                # PRNG keys are device arrays: stack them device-side instead
+                # of round-tripping each through np.asarray (a hidden sync)
+                keysb = jnp.stack([jnp.asarray(r.key) for r in padded])
             else:
-                carries.append(pso.default_carry(jnp.asarray(it.req.maskp)))
+                keysb = np.stack([np.asarray(r.key) for r in padded])
+            Qb = np.stack([r.Qp for r in padded])
+            Gb = np.stack([r.Gp for r in padded])
+            maskb = np.stack([r.maskp for r in padded])
+            carry0 = self._stack_carries(carries)
+            if self.mesh is None and self._donate_argnums("batch"):
+                self.stats.donated_launches += 1
 
-        pad = bclass - B
-        padded = list(reqs)
-        if pad:
-            pad_req, pad_carry = self._pad_slot(bucket, reqs[0], carries[0])
-            padded += [pad_req] * pad
-            carries = carries + [pad_carry] * pad
-            if pad_req is not reqs[0] and self.cfg.early_exit \
-                    and self.cfg.carry_fastpath:
-                self.stats.pad_slots_frozen += pad
-        if self.mesh is None:
-            # PRNG keys are device arrays: stack them device-side instead
-            # of round-tripping each through np.asarray (a hidden sync)
-            keysb = jnp.stack([jnp.asarray(r.key) for r in padded])
-        else:
-            keysb = np.stack([np.asarray(r.key) for r in padded])
-        Qb = np.stack([r.Qp for r in padded])
-        Gb = np.stack([r.Gp for r in padded])
-        maskb = np.stack([r.maskp for r in padded])
-        carry0 = self._stack_carries(carries)
-        if self.mesh is None and self._donate_argnums("batch"):
-            self.stats.donated_launches += 1
-
-        outs = fn(keysb, Qb, Gb, maskb, carry0)
-        self.stats.batch_launches += 1
-        self.stats.batch_problems += B
-        self.stats.batch_slots += bclass
-        self.stats.tier2.launches += 1
-        self.stats.epoch_fused_launches += 1
-        self.stats.epoch_finish_launches += 1
-        self.stats.epoch_finish_problems += B
-        self.stats.tier2.checked += B
-        return _LaunchRecord(kind="swarm", bucket=bucket, items=items,
-                             tier=2, B=B, bclass=bclass,
-                             compile_hit=compile_hit, outs=outs,
-                             padded=padded, t0=t0)
+            outs = fn(keysb, Qb, Gb, maskb, carry0)
+            self.stats.batch_launches += 1
+            self.stats.batch_problems += B
+            self.stats.batch_slots += bclass
+            self.stats.tier2.launches += 1
+            self.stats.epoch_fused_launches += 1
+            self.stats.epoch_finish_launches += 1
+            self.stats.epoch_finish_problems += B
+            self.stats.tier2.checked += B
+            return _LaunchRecord(kind="swarm", bucket=bucket, items=items,
+                                 tier=2, B=B, bclass=bclass,
+                                 compile_hit=compile_hit, outs=outs,
+                                 padded=padded, drain=drain)
 
     def _apply_swarm(self, rec: _LaunchRecord, host: dict) -> None:
         """Consume one fetched swarm launch: build per-item results from
         the host outputs, store the still-on-device controller state for
         future warm starts."""
-        items, B, padded = rec.items, rec.B, rec.padded
-        batch_results = collect_batch_results(
-            host, rec.bclass,
-            orders=[r.order for r in padded],
-            crops=[r.crop for r in padded])
-        done = time.perf_counter()
-        on_device = self.mesh is None
+        with _span("apply", tier=2, drain=rec.drain):
+            items, B, padded = rec.items, rec.B, rec.padded
+            batch_results = collect_batch_results(
+                host, rec.bclass,
+                orders=[r.order for r in padded],
+                crops=[r.crop for r in padded])
+            done = time.perf_counter()
+            on_device = self.mesh is None
 
-        self.stats.tier2.wall_s += done - rec.t0
-        for j, it in enumerate(items):
-            base = batch_results[j]
-            res = ServiceMatchResult(
-                **{f.name: getattr(base, f.name)
-                   for f in dataclasses.fields(MatchResult)})
-            dev_carry = (rec.outs["S_star"][j], rec.outs["f_star"][j],
-                         rec.outs["S_bar"][j]) if on_device else None
-            self._store_result_carries(it.req, it.warm_key, res,
-                                       dev_carry=dev_carry)
-            self.stats.epochs_run += res.epochs_run
-            self._note_prune(1, res.prune_sweeps)
-            if res.found:
-                self.stats.found += 1
-                self.stats.tier2.hits += 1
-            if res.carry_verified:
-                self.stats.carry_fastpath_hits += 1
-            res.bucket = rec.bucket
-            res.compile_cache_hit = rec.compile_hit
-            res.warm_hit = it.warm_hit
-            res.batch_size = B
-            res.coalesced = B > 1
-            res.tier = 2
-            # end-to-end drain latency: a Tier-2 request also waited out
-            # every pipeline launch that preceded this one
-            it.latency_s = done - it.t0
-            it.result = res
+            for j, it in enumerate(items):
+                base = batch_results[j]
+                res = ServiceMatchResult(
+                    **{f.name: getattr(base, f.name)
+                       for f in dataclasses.fields(MatchResult)})
+                dev_carry = (rec.outs["S_star"][j], rec.outs["f_star"][j],
+                             rec.outs["S_bar"][j]) if on_device else None
+                self._store_result_carries(it.req, it.warm_key, res,
+                                           dev_carry=dev_carry)
+                self.stats.epochs_run += res.epochs_run
+                self._note_prune(1, res.prune_sweeps)
+                if res.found:
+                    self.stats.found += 1
+                    self.stats.tier2.hits += 1
+                if res.carry_verified:
+                    self.stats.carry_fastpath_hits += 1
+                res.bucket = rec.bucket
+                res.compile_cache_hit = rec.compile_hit
+                res.warm_hit = it.warm_hit
+                res.batch_size = B
+                res.coalesced = B > 1
+                res.tier = 2
+                # end-to-end drain latency: a Tier-2 request also waited out
+                # every pipeline launch that preceded this one
+                it.latency_s = done - it.t0
+                it.result = res
 
     def _launch_batch_legacy(self, bucket, reqs: List[_PendingRequest],
                              tickets: List[int], results: List) -> None:
@@ -2116,7 +2168,6 @@ class MatcherService:
             out[f"{name}_checked"] = t.checked
             out[f"{name}_hits"] = t.hits
             out[f"{name}_hit_rate"] = t.hit_rate
-            out[f"{name}_wall_s"] = t.wall_s
         return out
 
 
@@ -2154,7 +2205,8 @@ class AsyncServiceFrontEnd:
     Every trigger reason, shed, forced drain, queue peak, and cumulative
     queue wait flows into the service's ``ServiceStats`` (``fe_*`` keys
     of ``stats_dict()``), so ``SimResult.matcher_stats`` →
-    ``metrics.frontend_stats`` report it per run.
+    ``metrics.frontend_stats`` report it per run. Each drain round runs
+    under an ``immsched.drain`` profiler span (see the module docstring).
 
     Time is an explicit ``now`` parameter everywhere (falling back to
     ``clock()``), so the front end drops into the event-driven simulator
@@ -2261,12 +2313,15 @@ class AsyncServiceFrontEnd:
         setattr(stats, f"fe_drain_{reason}",
                 getattr(stats, f"fe_drain_{reason}") + 1)
         batch, self._queue = self._queue, []
-        tickets = [self.service.submit(q.query, q.target, key=q.key,
-                                       workload_key=q.workload_key,
-                                       engine_sig=q.engine_sig)
-                   for q in batch]
-        results = self.service.drain()
-        for q, ticket in zip(batch, tickets):
-            self._results[q.rid] = results[ticket]
-            stats.fe_wait_s += max(now - q.enqueued_at, 0.0)
+        svc, n = self.service, stats.fe_drains
+        with _span("drain", drain=n, reason=reason, requests=len(batch)):
+            tickets = [svc.submit(q.query, q.target, key=q.key,
+                                  workload_key=q.workload_key,
+                                  engine_sig=q.engine_sig, rid=q.rid,
+                                  drain=n)
+                       for q in batch]
+            results = svc.drain()
+            for q, ticket in zip(batch, tickets):
+                self._results[q.rid] = results[ticket]
+                stats.fe_wait_s += max(now - q.enqueued_at, 0.0)
         return len(batch)
