@@ -112,6 +112,15 @@ def _span(name: str, **args) -> TraceAnnotation:
                            **{k: v for k, v in args.items() if v is not None})
 
 
+@functools.lru_cache(maxsize=1)
+def _default_key() -> np.ndarray:
+    """``PRNGKey(0)`` as a host array, for requests that bring no key:
+    host keys stack with numpy at dispatch, with no device op."""
+    key = np.asarray(jax.random.PRNGKey(0))
+    key.flags.writeable = False
+    return key
+
+
 def _round_up(v: int, mult: int) -> int:
     mult = max(mult, 1)
     return ((v + mult - 1) // mult) * mult
@@ -612,19 +621,36 @@ class CarryStore:
         return best
 
 
-@functools.lru_cache(maxsize=64)
-def _pool_writer(cap: int, n: int, m: int):
-    """Jitted donated row write for one slab shape: all three carry
-    parts land in their slabs in-place (``donate_argnums`` lets XLA
-    alias the outputs onto the input buffers, so a put never doubles
-    the slab's footprint). One trace per (capacity, n, m)."""
-    def write(Sb, fb, Cb, s, f, c, row):
-        Sb = jax.lax.dynamic_update_index_in_dim(Sb, s, row, 0)
-        fb = jax.lax.dynamic_update_index_in_dim(fb, f, row, 0)
-        Cb = jax.lax.dynamic_update_index_in_dim(Cb, c, row, 0)
-        return Sb, fb, Cb
+def carry_write_back(Sb, fb, Cb, S, f, C, idx):
+    """Write launch outputs into pool slabs in place. ``idx`` is (2, L)
+    int32 over the L output slots: row 0 the destination slab row (the
+    slab capacity, out of range, for a slot that stores nothing — the
+    scatter drops it), row 1 a flag storing f* = -inf instead of the
+    slot's f (a rebased Tier-2 seed)."""
+    rows, seed = idx[0], idx[1] != 0
+    f = jnp.where(seed, -jnp.inf, f.astype(jnp.float32))
+    return (Sb.at[rows].set(S.astype(jnp.float32), mode="drop"),
+            fb.at[rows].set(f, mode="drop"),
+            Cb.at[rows].set(C.astype(jnp.float32), mode="drop"))
 
-    return jax.jit(write, donate_argnums=(0, 1, 2))
+
+def carry_assemble(Sb, fb, Cb, idx, maskb):
+    """One launch's stacked ``(S*, f*, S̄)`` inputs: slot b takes slab row
+    ``idx[b]``, or the cold prior of ``maskb[b]`` where ``idx[b] < 0``.
+    The result is freshly allocated, so the launch may donate it."""
+    cold = idx < 0
+    rows = jnp.maximum(idx, 0)
+    S0, f0, C0 = pso.default_carry_batch(maskb)
+    c3 = cold[:, None, None]
+    return (jnp.where(c3, S0, jnp.take(Sb, rows, axis=0)),
+            jnp.where(cold, f0, jnp.take(fb, rows, axis=0)),
+            jnp.where(c3, C0, jnp.take(Cb, rows, axis=0)))
+
+
+# one program per (slab capacity, n, m, batch class): the slabs are
+# donated, so a write never doubles their footprint
+_carry_write_back = jax.jit(carry_write_back, donate_argnums=(0, 1, 2))
+_carry_assemble = jax.jit(carry_assemble)
 
 
 class _CarryHandle:
@@ -717,38 +743,38 @@ class _LazyCarry:
 class DeviceCarryPool:
     """Device-resident slab storage for warm-start carries.
 
-    Carries used to live in the :class:`CarryStore` as loose per-entry
-    arrays; every drain re-assembled its batch inputs with host
-    ``np.stack([np.asarray(...)])`` — a blocking device→host→device
-    round trip per launch. This pool keeps one growable slab triple per
-    padded shape — ``S``: (cap, n, m), ``f``: (cap,), ``C``: (cap, n, m),
-    all float32, all device-resident — and hands out refcounted
-    :class:`_CarryHandle` rows:
+    One growable slab triple per padded shape — ``S``: (cap, n, m),
+    ``f``: (cap,), ``C``: (cap, n, m), all float32, all device-resident —
+    handing out refcounted :class:`_CarryHandle` rows. Carries move
+    between the slabs and a tier launch through one compiled program per
+    launch in each direction:
 
-      * ``put`` writes a row through a donated jit update (in place, no
-        slab copy),
-      * ``gather`` turns a batch of handles into stacked launch inputs
-        with ONE ``jnp.take`` per part — device-side, dispatched
-        asynchronously, never a host sync,
+      * ``write_back`` stores chosen output slots of a launch as fresh
+        rows with ONE donated in-place program (``carry_write_back``);
+        ``put`` is its one-row case, for carries that arrive alone,
+      * ``assemble`` builds a launch's stacked carry input with ONE
+        program (``carry_assemble``): each slot takes a row, or the cold
+        prior computed from the launch's mask,
       * rows are recycled through a free list as store evictions release
         their handles.
 
     Slabs grow geometrically (``jnp.concatenate`` with a zero block), so
-    amortized put cost stays O(row). The pool never syncs to host; the
+    amortized write cost stays O(row). The pool never syncs to host; the
     persistence layer materializes handles lazily at snapshot-save time.
     """
 
     def __init__(self, block: int = 32):
         self.block = max(int(block), 1)
         self._slabs: Dict[Tuple[int, int], dict] = {}
-        self.puts = 0                # rows written (donated updates)
-        self.gathers = 0             # batched jnp.take gathers served
-        # steady-state warm drains gather the same row sets every time;
+        self.puts = 0                # rows written
+        self.writes = 0              # write programs run
+        self.gathers = 0             # assembly programs run
+        # steady-state drains assemble the same row sets every time;
         # caching the device index array saves a host→device transfer
         # dispatch per launch
         self._idx_cache: "OrderedDict[tuple, jax.Array]" = OrderedDict()
 
-    def _slab_for(self, shape: Tuple[int, int]) -> dict:
+    def _slab(self, shape: Tuple[int, int]) -> dict:
         slab = self._slabs.get(shape)
         if slab is None:
             n, m = shape
@@ -758,6 +784,11 @@ class DeviceCarryPool:
                     "C": jnp.zeros((cap, n, m), jnp.float32),
                     "free": list(range(cap - 1, -1, -1)), "cap": cap}
             self._slabs[shape] = slab
+        return slab
+
+    def _alloc(self, shape: Tuple[int, int]) -> int:
+        """Take a free row, growing the slab when none is left."""
+        slab = self._slab(shape)
         if not slab["free"]:
             old = slab["cap"]
             grow = max(old, self.block)
@@ -770,41 +801,55 @@ class DeviceCarryPool:
                 [slab["C"], jnp.zeros((grow, n, m), jnp.float32)])
             slab["cap"] = old + grow
             slab["free"] = list(range(old + grow - 1, old - 1, -1))
-        return slab
+        return slab["free"].pop()
+
+    def write_back(self, S, f, C, slots: Sequence[int],
+                   seeds: Sequence[int] = ()) -> List[_CarryHandle]:
+        """Store output slots of one launch — ``S``: (L, n, m), ``f``:
+        (L,), ``C``: (L, n, m), device or host — as fresh rows with one
+        program, and return their (unretained) handles: ``slots`` with
+        their own f*, then ``seeds`` with f* = -inf. The whole outputs go
+        in; the program picks the slots."""
+        shape = (int(S.shape[1]), int(S.shape[2]))
+        pos = list(slots) + list(seeds)
+        rows = [self._alloc(shape) for _ in pos]
+        slab = self._slabs[shape]
+        idx = np.zeros((2, S.shape[0]), np.int32)
+        idx[0] = slab["cap"]
+        idx[0, pos] = rows
+        idx[1, list(seeds)] = 1
+        slab["S"], slab["f"], slab["C"] = _carry_write_back(
+            slab["S"], slab["f"], slab["C"], S, f, C, idx)
+        self.puts += len(rows)
+        self.writes += 1
+        return [_CarryHandle(self, shape, r) for r in rows]
 
     def put(self, carry: tuple) -> _CarryHandle:
-        """Write one ``(S*, f*, S̄)`` carry into a slab row (donated
-        in-place update) and return its (unretained) handle. Accepts
-        device or host arrays; parts are cast to the slab's float32."""
-        S = jnp.asarray(carry[0], jnp.float32)
-        f = jnp.asarray(carry[1], jnp.float32)
-        C = jnp.asarray(carry[2], jnp.float32)
-        shape = (int(S.shape[0]), int(S.shape[1]))
-        slab = self._slab_for(shape)
-        row = slab["free"].pop()
-        writer = _pool_writer(slab["cap"], *shape)
-        slab["S"], slab["f"], slab["C"] = writer(
-            slab["S"], slab["f"], slab["C"], S, f, C, jnp.int32(row))
-        self.puts += 1
-        return _CarryHandle(self, shape, row)
+        """Write one ``(S*, f*, S̄)`` carry into a slab row and return its
+        (unretained) handle. Accepts device or host arrays; parts are
+        cast to the slab's float32."""
+        S, f, C = (p[None] if isinstance(p, jax.Array)
+                   else np.asarray(p, np.float32)[None] for p in carry)
+        return self.write_back(S, f, C, [0])[0]
 
-    def gather(self, handles: Sequence[_CarryHandle]) -> tuple:
-        """Stacked ``(S, f, C)`` launch inputs for a batch of same-shape
-        handles — one ``jnp.take`` per part, all on device. The result
-        is freshly allocated, so callers may donate it to a launch."""
-        shape = handles[0].shape
-        slab = self._slabs[shape]
-        rows = tuple(h.row for h in handles)
+    def assemble(self, carries: Sequence[Optional[_CarryHandle]],
+                 maskb) -> tuple:
+        """Stacked ``(S, f, C)`` launch inputs for one batch with mask
+        stack ``maskb`` (B, n, m): slot b takes the row of
+        ``carries[b]``, or the cold prior of ``maskb[b]`` where it is
+        None — one program, all on device. The result is freshly
+        allocated, so callers may donate it to a launch."""
+        slab = self._slab((int(maskb.shape[1]), int(maskb.shape[2])))
+        rows = tuple(-1 if c is None else c.row for c in carries)
         idx = self._idx_cache.get(rows)
         if idx is None:
-            idx = jnp.asarray(rows, jnp.int32)
+            # from a numpy array: a plain upload, no conversion program
+            idx = jnp.asarray(np.asarray(rows, np.int32))
             self._idx_cache[rows] = idx
             while len(self._idx_cache) > 256:
                 self._idx_cache.popitem(last=False)
         self.gathers += 1
-        return (jnp.take(slab["S"], idx, axis=0),
-                jnp.take(slab["f"], idx, axis=0),
-                jnp.take(slab["C"], idx, axis=0))
+        return _carry_assemble(slab["S"], slab["f"], slab["C"], idx, maskb)
 
     def _read(self, shape: Tuple[int, int], row: int) -> tuple:
         slab = self._slabs[shape]
@@ -1107,25 +1152,16 @@ class MatcherService:
         if self.warm_start:
             self._carries.put(warm_key, carry)
 
-    def _store_result_carries(self, req: _PendingRequest, warm_key,
-                              res: MatchResult, dev_carry=None) -> None:
-        """Store a fresh carry under the exact key, and — when the call
-        produced a served decision on a known platform state — under the
-        similarity key too, so future drifted states can rebase it.
-
-        ``dev_carry`` (the launch's still-on-device ``(S*, f*, S̄)``
-        slices) keeps the stored copy device-resident: it lands in the
-        :class:`DeviceCarryPool` without ever visiting the host. Without
-        it the result's host carry is uploaded once at store time."""
-        if not self.warm_start:
-            return
-        carry = res.carry if dev_carry is None else dev_carry
-        # mesh-sharded services skip the (single-device) pool: their
-        # launch outputs carry mesh shardings the slabs can't hold
-        stored = self._pool.put(self._carry_tuple(carry)) \
-            if self.mesh is None else res.carry
+    def _store_carry(self, req: _PendingRequest, warm_key, stored,
+                     similar: bool) -> None:
+        """Store a fresh carry (a pool handle, or the host carry on a
+        mesh service, whose launch outputs carry mesh shardings the
+        single-device slabs can't hold) under the exact key, and — when
+        the call produced a served decision (``similar``) on a known
+        platform state — under the similarity key too, so future drifted
+        states can rebase it."""
         self._put_carry(warm_key, stored)
-        if (self.similarity and res.found and req.engine_sig is not None):
+        if similar and self.similarity and req.engine_sig is not None:
             self._carries.put_similar(req.qdigest, req.bucket,
                                       req.engine_sig, stored)
 
@@ -1321,7 +1357,7 @@ class MatcherService:
         existing naming convention."""
         with _span("prepare", rid=rid, drain=drain):
             if key is None:
-                key = jax.random.PRNGKey(0)
+                key = _default_key()
             if engine_sig is None and isinstance(workload_key, tuple) \
                     and workload_key and isinstance(workload_key[-1], bytes):
                 engine_sig = workload_key[-1]
@@ -1401,46 +1437,37 @@ class MatcherService:
             return carry.materialize()
         return carry
 
+    @property
+    def _pooled(self) -> bool:
+        """Whether launches take their carries straight from the device
+        pool (``DeviceCarryPool.assemble``): the single-device pipelined
+        drain. Mesh services and the ``pipelined=False`` arm stage them
+        through host numpy (``_stack_carries``)."""
+        return self.mesh is None and self.pipelined
+
     def _stack_carries(self, carries: List) -> tuple:
-        """Stacked ``(B, ...)`` carry inputs for one launch, device-side.
-
-        All-handle same-shape batches (the warm steady state) take the
-        pool's one-``jnp.take``-per-part gather; mixed batches (cold
-        priors, rebased seeds, pad fillers) fall back to a device-side
-        ``jnp.stack`` of the materialized parts. Either way the result
-        is freshly allocated — safe to donate — and nothing round-trips
-        through the host.
-
-        Mesh services and the ``pipelined=False`` arm instead keep the
-        legacy host staging this PR replaced: each carry part is pulled
-        to host with a blocking ``np.asarray`` and re-stacked with
-        numpy. Those implicit device→host transfers are what the
-        pipeline eliminates, so they are charged to the host-sync
-        census here (one sync per device-resident part)."""
-        if self.mesh is not None or not self.pipelined:
-            mats = [self._carry_tuple(c) for c in carries]
-            stacked = []
-            for i in range(3):
-                parts = []
-                for mat in mats:
-                    p = mat[i]
-                    if isinstance(p, jax.Array):
-                        t0 = time.perf_counter()
-                        p = np.asarray(p)
-                        self.stats.host_syncs += 1
-                        self.stats.host_sync_wall_s += \
-                            time.perf_counter() - t0
-                        self.stats.host_bytes_transferred += int(p.nbytes)
-                    parts.append(np.asarray(p))
-                stacked.append(np.stack(parts))
-            return tuple(stacked)
-        if all(isinstance(c, _CarryHandle) for c in carries) and \
-                len({c.shape for c in carries}) == 1:
-            return self._pool.gather(carries)
+        """Stacked ``(B, ...)`` carry inputs for one launch of a mesh
+        service or the ``pipelined=False`` arm: the legacy host staging
+        the pooled path replaced. Each carry part is pulled to host with
+        a blocking ``np.asarray`` and re-stacked with numpy. Those
+        implicit device→host transfers are what the pooled path
+        eliminates, so they are charged to the host-sync census here
+        (one sync per device-resident part)."""
         mats = [self._carry_tuple(c) for c in carries]
-        return tuple(jnp.stack([jnp.asarray(m[i], jnp.float32)
-                                for m in mats])
-                     for i in range(3))
+        stacked = []
+        for i in range(3):
+            parts = []
+            for mat in mats:
+                p = mat[i]
+                if isinstance(p, jax.Array):
+                    t0 = time.perf_counter()
+                    p = np.asarray(p)
+                    self.stats.host_syncs += 1
+                    self.stats.host_sync_wall_s += time.perf_counter() - t0
+                    self.stats.host_bytes_transferred += int(p.nbytes)
+                parts.append(np.asarray(p))
+            stacked.append(np.stack(parts))
+        return tuple(stacked)
 
     def _donate_argnums(self, kind: str) -> Tuple[int, ...]:
         """Argnums a fresh jit build of ``kind`` may donate (empty when
@@ -1503,7 +1530,7 @@ class MatcherService:
         compile_hit = self.stats.compile_cache_hits > hits_before
 
         if carry0 is None:
-            carry0 = seed if seed is not None \
+            carry0 = self._carry_tuple(seed) if seed is not None \
                 else pso.default_carry(jnp.asarray(maskp))
         else:
             carry0 = self._carry_tuple(carry0)
@@ -1515,15 +1542,20 @@ class MatcherService:
                                       for a in self.axis_names]))
             keys = jax.random.split(key, num_shards)
             outs = fn(keys, Qp, Gp, maskp, carry0)
+        if isinstance(seed, _CarryHandle):
+            seed.release()
 
         # the controller state stays device-resident for the store; the
         # result itself resolves through ONE counted blocking fetch
-        dev_carry = (outs["S_star"], outs["f_star"], outs["S_bar"])
         base = collect_result(self._sync_fetch(outs), order=order,
                               crop=(n, m))
         res = ServiceMatchResult(**{f.name: getattr(base, f.name)
                                     for f in dataclasses.fields(MatchResult)})
-        self._store_result_carries(req, warm_key, res, dev_carry=dev_carry)
+        if self.warm_start:
+            stored = self._pool.put((outs["S_star"], outs["f_star"],
+                                     outs["S_bar"])) \
+                if self.mesh is None else res.carry
+            self._store_carry(req, warm_key, stored, similar=res.found)
         self.stats.epochs_run += res.epochs_run
         self._note_prune(1, res.prune_sweeps)
         if res.found:
@@ -1695,7 +1727,7 @@ class MatcherService:
         resolves through a single batched fetch (``_apply_all``).
 
         Host-side tier decisions for later groups (padding, carry
-        gathers, store probes) overlap device execution of earlier
+        assembly, store probes) overlap device execution of earlier
         groups' launches, and the per-stage sync count is 1 instead of
         one per launch. Results and stored carries are bitwise identical
         to the serial walk: store keys embed the bucket, so groups never
@@ -1825,7 +1857,11 @@ class MatcherService:
             Qb = np.stack([r.Qp for r in padded])
             Gb = np.stack([r.Gp for r in padded])
             maskb = np.stack([r.maskp for r in padded])
-            carry0 = self._stack_carries(carries)
+            if self._pooled:
+                maskb = jnp.asarray(maskb)
+                carry0 = self._pool.assemble(carries, maskb)
+            else:
+                carry0 = self._stack_carries(carries)
             if self.mesh is None and self._donate_argnums("reval"):
                 self.stats.donated_launches += 1
 
@@ -1844,8 +1880,7 @@ class MatcherService:
         refresh stores. All array reads come from ``host`` or stay on
         device — this path never blocks."""
         with _span("apply", tier=rec.tier, drain=rec.drain):
-            tier, B, items = rec.tier, rec.B, rec.items
-            bucket, carries = rec.bucket, rec.carries
+            tier, B, items, carries = rec.tier, rec.B, rec.items, rec.carries
             tstats = self.stats.tier0 if tier == 0 else self.stats.tier1
             # Tier 0 re-validates this problem's own carry (carried-f* gate);
             # Tier 1 additionally requires the rebased projection to clear the
@@ -1859,20 +1894,30 @@ class MatcherService:
             f_carry = host.get("f_carry")
             sweeps = np.asarray(host["prune_sweeps"]).reshape(-1)
             self._note_prune(B, int(sweeps[:B].sum()))
-            on_device = self.mesh is None
             done = time.perf_counter()
+            # Tier 1 stores every hit's rebased carry, and keeps each
+            # cold miss's rebased carry (f* reset to -inf) as its Tier-2
+            # seed; a miss with a failed exact carry swarms from that
+            # instead. Single-device services write them all to pool rows
+            # in one program; a seed row is held by its item until its
+            # swarm launch is dispatched
+            written = {}
+            if tier == 1:
+                hit = [j for j in range(B) if ok[j]]
+                seed = [j for j, it in enumerate(items)
+                        if not ok[j] and it.carry is None]
+                if self.mesh is None and (hit or seed):
+                    written = dict(zip(hit + seed, self._pool.write_back(
+                        rec.outs["S_star"], rec.outs["fitness"],
+                        rec.outs["S_bar"], hit, seed)))
 
             for j, it in enumerate(items):
                 it.latency_s = done - it.t0
                 if not ok[j]:
-                    if tier == 1:
-                        # rebased controller state seeds the Tier-2 swarm;
-                        # keep it device-resident (slices of the launch
-                        # outputs) so the swarm stack never touches host
-                        if on_device:
-                            it.seed = (rec.outs["S_star"][j],
-                                       np.float32(-np.inf),
-                                       rec.outs["S_bar"][j])
+                    if tier == 1 and it.carry is None:
+                        if j in written:
+                            it.seed = written[j]
+                            it.seed.retain()
                         else:
                             it.seed = (S_rb[j], np.float32(-np.inf),
                                        S_bar_rb[j])
@@ -1893,15 +1938,8 @@ class MatcherService:
                 else:
                     carry = (S_rb[j], fits[j], S_bar_rb[j])
                     f_res = float(fits[j])
-                    if self.warm_start:
-                        stored = self._pool.put(
-                            (rec.outs["S_star"][j], rec.outs["fitness"][j],
-                             rec.outs["S_bar"][j])) if on_device else carry
-                        self._put_carry(it.warm_key, stored)
-                        if it.req.engine_sig is not None:
-                            self._carries.put_similar(it.req.qdigest, bucket,
-                                                      it.req.engine_sig,
-                                                      stored)
+                    self._store_carry(it.req, it.warm_key,
+                                      written.get(j, carry), similar=True)
                 it.result = self._revalidated_result(
                     it, maps[j], f_res, carry, tier=tier, batch=B,
                     compile_hit=rec.compile_hit, prune_sweeps=int(sweeps[j]))
@@ -1992,15 +2030,10 @@ class MatcherService:
             compile_hit = self.stats.compile_cache_hits > hits_before
 
             reqs = [it.req for it in items]
-            carries = []
-            for it in items:
-                if it.carry is not None:
-                    carries.append(it.carry)
-                elif it.seed is not None:
-                    carries.append(it.seed)
-                else:
-                    carries.append(
-                        pso.default_carry(jnp.asarray(it.req.maskp)))
+            # a slot's carry: its failed exact carry, its rebased seed,
+            # or None for the cold prior
+            carries = [it.carry if it.carry is not None else it.seed
+                       for it in items]
 
             pad = bclass - B
             padded = list(reqs)
@@ -2012,20 +2045,34 @@ class MatcherService:
                 if pad_req is not reqs[0] and self.cfg.early_exit \
                         and self.cfg.carry_fastpath:
                     self.stats.pad_slots_frozen += pad
-            if self.mesh is None:
-                # PRNG keys are device arrays: stack them device-side instead
-                # of round-tripping each through np.asarray (a hidden sync)
-                keysb = jnp.stack([jnp.asarray(r.key) for r in padded])
+            keys = [r.key for r in padded]
+            if self.mesh is None and not all(isinstance(k, np.ndarray)
+                                             for k in keys):
+                # device keys stack device-side: np.asarray would be a
+                # hidden sync per key
+                keysb = jnp.stack(keys)
             else:
-                keysb = np.stack([np.asarray(r.key) for r in padded])
+                keysb = np.stack([np.asarray(k) for k in keys])
             Qb = np.stack([r.Qp for r in padded])
             Gb = np.stack([r.Gp for r in padded])
             maskb = np.stack([r.maskp for r in padded])
-            carry0 = self._stack_carries(carries)
+            if self._pooled:
+                maskb = jnp.asarray(maskb)
+                carry0 = self._pool.assemble(carries, maskb)
+            else:
+                carry0 = self._stack_carries([
+                    c if c is not None
+                    else pso.default_carry(jnp.asarray(r.maskp))
+                    for c, r in zip(carries, padded)])
             if self.mesh is None and self._donate_argnums("batch"):
                 self.stats.donated_launches += 1
 
             outs = fn(keysb, Qb, Gb, maskb, carry0)
+            for it in items:
+                if isinstance(it.seed, _CarryHandle):
+                    # the launch has read the seed row: give it back
+                    it.seed.release()
+                    it.seed = None
             self.stats.batch_launches += 1
             self.stats.batch_problems += B
             self.stats.batch_slots += bclass
@@ -2050,17 +2097,24 @@ class MatcherService:
                 orders=[r.order for r in padded],
                 crops=[r.crop for r in padded])
             done = time.perf_counter()
-            on_device = self.mesh is None
+            # every item's controller state is stored for future warm
+            # starts: on a single-device service all B rows in one
+            # program, straight from the device outputs
+            if self.warm_start and self.mesh is None:
+                stored = self._pool.write_back(
+                    rec.outs["S_star"], rec.outs["f_star"],
+                    rec.outs["S_bar"], range(B))
+            else:
+                stored = [r.carry for r in batch_results[:B]]
 
             for j, it in enumerate(items):
                 base = batch_results[j]
                 res = ServiceMatchResult(
                     **{f.name: getattr(base, f.name)
                        for f in dataclasses.fields(MatchResult)})
-                dev_carry = (rec.outs["S_star"][j], rec.outs["f_star"][j],
-                             rec.outs["S_bar"][j]) if on_device else None
-                self._store_result_carries(it.req, it.warm_key, res,
-                                           dev_carry=dev_carry)
+                if self.warm_start:
+                    self._store_carry(it.req, it.warm_key, stored[j],
+                                      similar=res.found)
                 self.stats.epochs_run += res.epochs_run
                 self._note_prune(1, res.prune_sweeps)
                 if res.found:
@@ -2159,6 +2213,7 @@ class MatcherService:
             "host_sync_wall_s": s.host_sync_wall_s,
             "donated_launches": s.donated_launches,
             "pool_puts": self._pool.puts,
+            "pool_writes": self._pool.writes,
             "pool_gathers": self._pool.gathers,
             "pool_live_rows": self._pool.live_rows,
         }

@@ -126,8 +126,8 @@ def transfer_stats(result: SimResult) -> Dict[str, float]:
     ms = result.matcher_stats
     keys = ("drains", "host_syncs", "host_syncs_per_drain",
             "host_bytes_transferred", "host_sync_wall_s",
-            "donated_launches", "pool_puts", "pool_gathers",
-            "pool_live_rows")
+            "donated_launches", "pool_puts", "pool_writes",
+            "pool_gathers", "pool_live_rows")
     return {k: ms.get(k, 0) for k in keys}
 
 

@@ -1,7 +1,10 @@
 """Device-resident drain pipeline: the host-sync census (one blocking
-fetch per all-warm drain), pipelined-vs-serial bitwise parity, carry
-buffer donation, the device carry pool's row lifecycle, the pooled
-popcount index bookkeeping, and device-side best-feasible selection."""
+fetch per all-warm drain), pipelined-vs-serial bitwise parity, the
+pooled carry path's program budget, carry buffer donation, the device
+carry pool's row lifecycle, the pooled popcount index bookkeeping, and
+device-side best-feasible selection."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -136,6 +139,170 @@ def test_pipelined_matches_serial_bitwise():
 
 
 # ---------------------------------------------------------------------------
+# the pooled carry path: one assembly and one write-back program a launch
+# ---------------------------------------------------------------------------
+
+# free-engine signatures of three platform states of one workload: "more"
+# adds target edges to "base" (its stored carry rebases and usually
+# revalidates: a Tier-1 hit); "other" plants the query elsewhere (the
+# rebase usually fails: a Tier-1 miss that swarms from its rebased seed)
+_SIGS = {"base": b"\x0f\x00", "more": b"\x0f\x01", "other": b"\x0e\x10"}
+
+# drains of (query seed, n, m, platform state); buckets (8, 16), (8, 32)
+_A, _B = (6, 12), (5, 24)
+_DRAINS = {
+    # batch classes 1, 2, 4 (one pad slot) and 8, all cold priors
+    "cold": [[(s, *_A, "base") for s in range(3)] + [(0, *_B, "base")],
+             [(s, *_A, "base") for s in range(3, 11)]
+             + [(s, *_B, "base") for s in (1, 2)]],
+    # exact repeats: Tier-0 hits, and failed exact carries that swarm
+    "warm": [[(s, *_A, "base") for s in range(5)]
+             + [(s, *_B, "base") for s in range(2)]] * 2,
+    # Tier-0 hits, Tier-1 hits, Tier-1 misses with seeds, cold requests
+    "mixed": [[(s, *_A, "base") for s in range(6)]
+              + [(s, *_B, "base") for s in range(3)],
+              [(0, *_A, "base"), (1, *_A, "base")]
+              + [(s, *_A, "more") for s in range(2, 6)]
+              + [(s, *_A, "other") for s in range(4)]
+              + [(6, *_A, "base"), (7, *_A, "base"),
+                 (0, *_B, "base"), (1, *_B, "more"), (2, *_B, "other"),
+                 (3, *_B, "base")]],
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _state(seed, n, m, var):
+    """(query, target, workload key) of one platform state."""
+    kq, kt = jax.random.split(jax.random.PRNGKey(seed))
+    q = graphs.random_dag(kq, n, 0.35)
+    if var == "more":
+        g = graphs.embed_query_in_target(kt, q, m, extra_edge_prob=0.3)
+    elif var == "other":
+        g = graphs.embed_query_in_target(jax.random.fold_in(kt, 1), q, m)
+    else:
+        g = graphs.embed_query_in_target(kt, q, m)
+    return q, g, (f"w{seed}/{n}x{m}", _SIGS[var])
+
+
+def _drain_states(svc, specs, host_keys=True):
+    for i, spec in enumerate(specs):
+        q, g, wk = _state(*spec)
+        key = jax.random.PRNGKey(1000 + i)
+        svc.submit(q, g, key=np.asarray(key) if host_keys else key,
+                   workload_key=wk, engine_sig=wk[1])
+    return svc.drain()
+
+
+def _stored(svc):
+    """Both carry stores as {key: host bytes}. (Their recency order
+    across buckets differs between the arms: the pipelined drain runs
+    each tier for every bucket before the next tier.)"""
+    exact, sim = svc._carries.export_state()
+    return [{k: tuple(np.asarray(p, np.float32).tobytes()
+                      for p in svc._carry_tuple(c)) for k, c in items}
+            for items in (exact, sim)]
+
+
+@pytest.mark.parametrize("case", sorted(_DRAINS))
+def test_pooled_drain_matches_serial_arm_bitwise(case):
+    """The pooled carry path (assembly from pool rows and cold priors,
+    one write-back per launch) serves and stores bitwise what the
+    host-staged ``pipelined=False`` arm does, over cold, mixed and warm
+    multi-bucket drains; every stored carry is the launch output the
+    result was collected from."""
+    pipe = MatcherService(CFG)
+    ser = MatcherService(CFG, pipelined=False)
+    for specs in _DRAINS[case]:
+        rp = _drain_states(pipe, specs, host_keys=case != "cold")
+        rs = _drain_states(ser, specs, host_keys=case != "cold")
+        for a, b in zip(rp, rs):
+            assert _result_fingerprint(a) == _result_fingerprint(b)
+        assert _stored(pipe) == _stored(ser)
+        assert pipe._pool.live_rows == ser._pool.live_rows
+        for spec, r in zip(specs, rp):
+            if r.tier == 0:
+                continue
+            q, g, wk = _state(*spec)
+            stored = pipe._carries._exact[
+                pipe._warm_key(pipe._prepare(q, g, None, wk))]
+            for got, want in zip(pipe._carry_tuple(stored), r.carry):
+                np.testing.assert_array_equal(
+                    np.asarray(got), np.asarray(want, np.float32))
+    s = pipe.stats_dict()
+    if case != "warm":
+        assert s["pad_slots_frozen"] > 0
+    if case != "cold":
+        assert s["tier0_hits"] > 0
+    if case == "mixed":
+        assert 0 < s["tier1_hits"] < s["tier1_checked"]
+    assert s["pool_writes"] > 0 and s["pool_gathers"] > 0
+
+
+def test_carry_path_glue_budget(monkeypatch):
+    """One write-back program per launch that stores rows, no slicing of
+    launch outputs in either apply, and a seed's pool row back on the
+    free list once its swarm launch is dispatched."""
+    svc = MatcherService(CFG)
+    _drain_states(svc, _DRAINS["mixed"][0])
+
+    array_type = type(jnp.zeros(1))
+    in_apply, sliced = [], []
+    get_item = array_type.__getitem__
+
+    def spy_getitem(self, idx):
+        if in_apply:
+            sliced.append(idx)
+        return get_item(self, idx)
+
+    monkeypatch.setattr(array_type, "__getitem__", spy_getitem)
+    storing, rows, seeds = [], [], []
+
+    def wrap_apply(name):
+        orig = getattr(svc, name)
+
+        def apply(rec, host):
+            in_apply.append(rec)
+            try:
+                orig(rec, host)
+            finally:
+                in_apply.pop()
+            # Tier 0 stores nothing; Tier 1 its hits and held seeds;
+            # Tier 2 every item
+            stored = [it for it in rec.items if rec.tier == 2
+                      or rec.tier == 1 and (it.result is not None
+                                            or it.seed is not None)]
+            if stored:
+                storing.append(rec)
+                rows.append(len(stored))
+        monkeypatch.setattr(svc, name, apply)
+
+    wrap_apply("_apply_swarm")
+    wrap_apply("_apply_revalidate")
+    dispatch_swarm = svc._dispatch_swarm
+
+    def dispatch(bucket, items):
+        held = [it.seed for it in items if it.seed is not None]
+        rec = dispatch_swarm(bucket, items)
+        for h in held:
+            seeds.append(h)
+            assert h.row == -1 and h.refs == 0
+        return rec
+
+    monkeypatch.setattr(svc, "_dispatch_swarm", dispatch)
+    before = svc.stats_dict()
+    _drain_states(svc, _DRAINS["mixed"][1])
+    after = svc.stats_dict()
+    assert sliced == []
+    assert after["pool_writes"] - before["pool_writes"] == len(storing) > 0
+    assert after["pool_puts"] - before["pool_puts"] == sum(rows)
+    assert seeds, "the mixed drain has no Tier-1 miss that swarms"
+    # every live row is held by a store entry or a pad: no seed leaked
+    held = {id(c) for items in svc._carries.export_state()
+            for _, c in items} | {id(h) for h in svc._pad_handles.values()}
+    assert svc._pool.live_rows == len(held)
+
+
+# ---------------------------------------------------------------------------
 # buffer donation
 # ---------------------------------------------------------------------------
 
@@ -176,16 +343,26 @@ def test_pool_put_gather_roundtrip():
     pool = DeviceCarryPool(block=4)
     carries = [_carry(fill=float(i), f=float(i)) for i in range(3)]
     handles = [pool.put(c) for c in carries]
-    S, f, C = pool.gather(handles)
-    assert S.shape == (3, 4, 8)
-    np.testing.assert_array_equal(np.asarray(f),
-                                  np.asarray([0.0, 1.0, 2.0], np.float32))
+    # the assembly program gathers rows and fills None slots with the
+    # cold prior of their mask
+    maskb = np.zeros((4, 4, 8), np.uint8)
+    maskb[3, :, :2] = 1
+    S, f, C = pool.assemble(handles + [None], jnp.asarray(maskb))
+    assert S.shape == (4, 4, 8)
+    np.testing.assert_array_equal(
+        np.asarray(f), np.asarray([0.0, 1.0, 2.0, -np.inf], np.float32))
     for i, h in enumerate(handles):
         s_i, f_i, c_i = h.materialize()
         np.testing.assert_array_equal(np.asarray(s_i), carries[i][0])
         np.testing.assert_array_equal(np.asarray(c_i), carries[i][2])
+        np.testing.assert_array_equal(np.asarray(S[i]), carries[i][0])
+        np.testing.assert_array_equal(np.asarray(C[i]), carries[i][2])
+    cold = pso.default_carry(jnp.asarray(maskb[3]))
+    np.testing.assert_array_equal(np.asarray(S[3]), np.asarray(cold[0]))
+    np.testing.assert_array_equal(np.asarray(C[3]), np.asarray(cold[2]))
     assert pool.gathers == 1
     assert pool.puts == 3
+    assert pool.writes == 3
 
 
 def test_pool_rows_recycle_on_release():
