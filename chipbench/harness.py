@@ -6,9 +6,12 @@ A cell (``BENCHMARK.json`` ``workloads``) names a configuration
 (``traffic/<mix>.json``, read by ``generator.py``); ``cells/<cell>.json``
 holds what belongs to the pair: the offered rate, fixed from a sweep on
 the chip, the shape buckets its traffic may reach, and the limits of
-its checks. Per-layer metrics are read by ``metrics/<metric>.py``. All
-of these are found by name, so a later cell, configuration, mix or
-metric is a new file and a new entry.
+its checks. Per-layer metrics are read by ``metrics/<metric>.py``. The
+query windows come from the window set the configuration names under
+``windows`` (its platform's name when it names none), frozen in
+``data/windows/<set>.json`` by ``freeze_windows.py``. All of these are
+found by name, so a later cell, configuration, mix, window set or metric
+is a new file and a new entry.
 
 One run:
 
@@ -94,6 +97,7 @@ class Cell:
     params: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    windows: Dict[str, reference.Window]
 
     @property
     def platform(self) -> Dict:
@@ -109,24 +113,44 @@ def _in_cell(metric: Dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
-def load_cell(name: str, spec_file: str = SPEC_FILE) -> Cell:
+def load_cell(name: str, spec_file: str = SPEC_FILE,
+              windows_dir: str = WINDOWS_DIR) -> Cell:
+    """The cell, its files and its window set; raises :class:`Refused`
+    when the set was lowered for another platform than the
+    configuration's."""
     spec = _read_json(spec_file)
     entry = next((w for w in spec["workloads"] if w["name"] == name), None)
     if entry is None:
         raise Refused(f"no workload {name!r} in {spec_file}")
     conf = next(c for c in spec["configs"] if c["name"] == entry["config"])
+    config = _read_json(os.path.join(ROOT, conf["file"]))
     return Cell(
-        name=name, entry=entry,
-        config=_read_json(os.path.join(ROOT, conf["file"])),
+        name=name, entry=entry, config=config,
         traffic=_read_json(os.path.join(HERE, "traffic",
                                         entry["traffic"] + ".json")),
         params=_read_json(os.path.join(HERE, "cells", name + ".json")),
         end_to_end=[m for m in spec["end_to_end"] if _in_cell(m, name)],
-        per_layer=[m for m in spec["per_layer"] if _in_cell(m, name)])
+        per_layer=[m for m in spec["per_layer"] if _in_cell(m, name)],
+        windows=load_windows(window_set(config), config["platform"]["name"],
+                             windows_dir))
 
 
-def load_windows(platform_name: str) -> Dict[str, reference.Window]:
-    data = _read_json(os.path.join(WINDOWS_DIR, platform_name + ".json"))
+def window_set(config: Dict) -> str:
+    """The window set a configuration is served from: its ``windows``
+    key, else its platform's name."""
+    return config.get("windows", config["platform"]["name"])
+
+
+def load_windows(set_name: str, platform_name: str,
+                 windows_dir: str = WINDOWS_DIR
+                 ) -> Dict[str, reference.Window]:
+    """The frozen windows of ``<windows_dir>/<set_name>.json``; raises
+    :class:`Refused` when the set was lowered for another platform than
+    ``platform_name``."""
+    data = _read_json(os.path.join(windows_dir, set_name + ".json"))
+    if data["platform"] != platform_name:
+        raise Refused(f"window set {set_name!r} was lowered for platform "
+                      f"{data['platform']!r}, not {platform_name!r}")
     return {name: reference.Window(name, w["n"], w["edges"], w["types"],
                                    w["macs"])
             for name, w in data["windows"].items()}
@@ -247,10 +271,16 @@ def make_problems(cell: Cell, windows, reqs, seed: int, stream: int
                for name, w in windows.items()}
     keys = generator.rng_for(seed, STREAM_KEYS * 1000 + stream).integers(
         0, 1 << 32, size=(len(reqs), 2), dtype=np.uint32)
-    return [Problem(req=r, query=queries[r.window],
-                    target=free_engine_graph(plat, r.free),
-                    sig=free_engine_signature(r.free), key=keys[i])
-            for i, r in enumerate(reqs)]
+    # one target graph per free mask: requests that repeat a state share it
+    targets: Dict[bytes, object] = {}
+    out = []
+    for i, r in enumerate(reqs):
+        sig = free_engine_signature(r.free)
+        if sig not in targets:
+            targets[sig] = free_engine_graph(plat, r.free)
+        out.append(Problem(req=r, query=queries[r.window],
+                           target=targets[sig], sig=sig, key=keys[i]))
+    return out
 
 
 def plant_fault(kind: str):
@@ -720,7 +750,7 @@ def run_cell(cell: Cell, args, dev: Dict, t_start: float,
 def _run_cell(cell: Cell, args, dev: Dict, t_start: float, persist_dir,
               compiled: List[str]) -> Optional[Dict]:
     import jax
-    windows = load_windows(cell.platform["name"])
+    windows = cell.windows
     rate = cell.params["rate_hz"]
     svc, fe, problems = prepare(cell, args.seed, args.seconds, rate,
                                 persist_dir, windows, compiled)

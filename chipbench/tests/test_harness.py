@@ -20,11 +20,15 @@ from chipbench import harness, roofline
 from chipbench.metrics_common import swarm_problems
 
 ROOT = harness.ROOT
+#: Four repeated states of two small windows: answers from the stores.
+POOL = {"kind": "zipf_pool", "names": ["mobilenetv2", "efficientnet"],
+        "states": 4, "exponent": 1.0, "swap_frac": 0.25}
 
 
 def tiny_cell(names=("mobilenetv2", "efficientnet")):
     """The edge cell with its traffic and swarm cut to what the CPU can
-    serve in seconds (small windows, two batch classes)."""
+    serve in seconds (small windows, two batch classes); ``names`` are
+    the windows served uniformly, or a pool mix's ``windows``."""
     cell = harness.load_cell("edge.burst_mixed")
     cell.config = copy.deepcopy(cell.config)
     cell.traffic = copy.deepcopy(cell.traffic)
@@ -33,7 +37,8 @@ def tiny_cell(names=("mobilenetv2", "efficientnet")):
     cell.config["service"]["batch_classes"] = [1, 2]
     cell.traffic.update(warm_pool=20, preroll=8, preroll_rounds=2, fill=12)
     cell.config["service"].update(warm_capacity=8, sim_capacity=4)
-    cell.traffic["windows"] = {"kind": "uniform", "names": list(names)}
+    cell.traffic["windows"] = (dict(names) if isinstance(names, dict) else
+                               {"kind": "uniform", "names": list(names)})
     return cell
 
 
@@ -75,6 +80,9 @@ def test_sound_run_is_correct(sound):
     # nasnet's window has odd cycles: the mesh holds no mapping of it
     ("claim_found", ("nasnet",), "found_without_mapping"),
     ("drop_half", ("mobilenetv2", "efficientnet"), "missed_mappings_pct"),
+    # repeated states, answered from the Tier-0/1 stores
+    ("alter_answer", POOL, "invalid_mappings"),
+    ("drop_half", POOL, "missed_mappings_pct"),
 ])
 def test_fault_makes_the_run_incorrect(fault, names, check):
     res = run_tiny(fault=fault, names=names)
@@ -96,14 +104,23 @@ def test_traced_run_reports_the_layer_metrics_it_can_read():
 def test_a_mix_of_repeated_states_is_served_from_its_pool(capfd):
     """A ``zipf_pool`` mix needs no code of its own: set-up serves each
     pool state once, and the window's repeats are answered correctly."""
-    cell = tiny_cell()
-    cell.traffic["windows"] = {"kind": "zipf_pool",
-                               "names": ["mobilenetv2", "efficientnet"],
-                               "states": 4, "exponent": 1.0,
-                               "swap_frac": 0.25}
-    res = run_tiny(cell=cell)
+    res = run_tiny(names=POOL)
     assert res["correct"] is True and res["failed"] == 0
     assert "pool: 4 states" in capfd.readouterr().err
+
+
+def test_a_run_serves_the_window_set_of_its_cell():
+    """A run serves the windows of the cell's window set, here one
+    lowered from stages other than 0, and is correct."""
+    cell = tiny_cell()
+    cell.windows = harness.load_windows(
+        "two_windows", cell.platform["name"],
+        os.path.join(os.path.dirname(__file__), "data", "windows"))
+    cell.traffic["windows"] = {"kind": "uniform", "names": sorted(
+        cell.windows)}
+    res = run_tiny(cell=cell)
+    assert res["correct"] is True and res["failed"] == 0
+    assert res["metrics"]["mapped_pct"]["value"] == 100.0
 
 
 def test_roofline_count_ignores_padding():
@@ -116,7 +133,7 @@ def test_roofline_count_ignores_padding():
     from repro.core.graphs import Graph
     from repro.core.pso import PSOConfig
     from repro.core.service import MatcherService
-    windows = harness.load_windows("edge")
+    windows = harness.load_windows("edge", "edge")
     w = windows["resnet50"]
     free = [True] * 64
     free[5] = free[17] = False

@@ -1,8 +1,10 @@
-"""The benchmark's inputs: seeded traffic, listed buckets, frozen windows.
+"""The benchmark's inputs: seeded traffic, listed buckets, frozen window
+sets.
 
     python -m pytest chipbench/tests
 """
 import copy
+import glob
 import json
 import os
 
@@ -14,6 +16,12 @@ from chipbench import freeze_windows, generator, harness
 SPEC = json.load(open(harness.SPEC_FILE))
 CELLS = [w["name"] for w in SPEC["workloads"]]
 SEEDS = (0, 7, 2**31 + 12345, 3 * 2**40 + 1)
+TEST_WINDOWS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                "data", "windows")
+#: Every frozen window set: the benchmark's own and the tests' own.
+WINDOW_SETS = sorted(glob.glob(os.path.join(freeze_windows.WINDOWS_DIR,
+                                            "*.json"))
+                     + glob.glob(os.path.join(TEST_WINDOWS_DIR, "*.json")))
 
 
 def _warm_zipf():
@@ -81,7 +89,7 @@ def test_every_seed_same_work_in_another_order(name):
 @pytest.mark.parametrize("name", MIXES)
 def test_traffic_stays_in_listed_buckets(name):
     cell = _cell(name)
-    windows = harness.load_windows(cell.platform["name"])
+    windows = cell.windows
     seen = set()
     svc = cell.config["service"]
     for seed in SEEDS:
@@ -106,7 +114,7 @@ def test_traffic_stays_in_listed_buckets(name):
 
 def test_traffic_outside_the_buckets_is_refused():
     cell = harness.load_cell("cloud.cold_poisson")
-    windows = harness.load_windows("cloud")
+    windows = cell.windows
     cell.traffic = copy.deepcopy(cell.traffic)
     cell.traffic["masks"]["extra_busy"] = 40
     with pytest.raises(harness.Refused):
@@ -170,16 +178,72 @@ def test_pool_requests_repeat_their_states():
         assert top > len(reqs) / 8      # rank 1 of 64 under Zipf 1.0
 
 
-@pytest.mark.parametrize("platform", ["cloud", "edge"])
-def test_frozen_windows_equal_the_lowering(platform):
-    path = os.path.join(freeze_windows.WINDOWS_DIR, platform + ".json")
-    frozen = json.load(open(path))
-    assert frozen["window_stages"] == freeze_windows.WINDOW_STAGES
-    assert frozen["windows"] == freeze_windows.lowered_windows(platform)
+@pytest.mark.parametrize(
+    "path", WINDOW_SETS,
+    ids=[os.path.basename(p)[:-len(".json")] for p in WINDOW_SETS])
+def test_frozen_windows_equal_the_lowering(path):
+    """Each window of each set, lowered again from its recorded source,
+    gives the set's file byte for byte: ``freeze_windows.py`` would
+    write it unchanged."""
+    with open(path) as f:
+        text = f.read()
+    assert freeze_windows.dumps(freeze_windows.refrozen(json.loads(text))) \
+        == text
+
+
+def test_platform_sets_are_the_zoo_workloads_from_stage_0():
+    for platform in freeze_windows.PLATFORM_SETS:
+        data = harness._read_json(os.path.join(freeze_windows.WINDOWS_DIR,
+                                               platform + ".json"))
+        assert data["platform"] == platform
+        assert data["window_stages"] == 4
+        for name, w in data["windows"].items():
+            assert "source" not in w
+            assert freeze_windows.source_of(name, w) == {
+                "workload": name, "args": {}, "progress": 0}
+
+
+def _spec_naming(tmp_path, config_name, set_name):
+    """A copy of the benchmark's spec in which the configuration
+    ``config_name`` names the window set ``set_name``."""
+    spec = copy.deepcopy(SPEC)
+    conf = next(c for c in spec["configs"] if c["name"] == config_name)
+    config = harness._read_json(os.path.join(harness.ROOT, conf["file"]))
+    config["windows"] = set_name
+    conf["file"] = str(tmp_path / "config.json")
+    (tmp_path / "config.json").write_text(json.dumps(config))
+    (tmp_path / "spec.json").write_text(json.dumps(spec))
+    return str(tmp_path / "spec.json")
+
+
+def test_a_configuration_is_served_from_the_set_it_names(tmp_path):
+    spec_file = _spec_naming(tmp_path, "edge64.q8", "two_windows")
+    cell = harness.load_cell("edge.burst_mixed", spec_file=spec_file,
+                             windows_dir=TEST_WINDOWS_DIR)
+    assert harness.window_set(cell.config) == "two_windows"
+    frozen = harness._read_json(os.path.join(
+        TEST_WINDOWS_DIR, "two_windows.json"))["windows"]
+    assert set(cell.windows) == set(frozen) == {"resnet50.r112.s1",
+                                                "mobilenetv2.s2"}
+    for name, w in cell.windows.items():
+        assert w.n == frozen[name]["n"]
+        assert w.macs.tolist() == frozen[name]["macs"]
+    # without the key, the set is the platform's
+    plain = harness.load_cell("edge.burst_mixed")
+    assert harness.window_set(plain.config) == "edge"
+    assert set(plain.windows) == set(harness._read_json(os.path.join(
+        freeze_windows.WINDOWS_DIR, "edge.json"))["windows"])
+
+
+def test_a_set_of_another_platform_is_refused(tmp_path):
+    spec_file = _spec_naming(tmp_path, "cloud128.q8", "two_windows")
+    with pytest.raises(harness.Refused, match="platform 'edge'"):
+        harness.load_cell("cloud.cold_poisson", spec_file=spec_file,
+                          windows_dir=TEST_WINDOWS_DIR)
 
 
 def test_frozen_window_sizes():
-    w = harness.load_windows("cloud")
+    w = harness.load_windows("cloud", "cloud")
     assert {k: v.n for k, v in w.items()} == {
         "mobilenetv2": 4, "efficientnet": 4, "deepseek-7b": 5,
         "qwen-7b": 5, "llama3-8b-wl": 5, "resnet50": 6, "nasnet": 16,

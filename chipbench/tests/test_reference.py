@@ -58,7 +58,7 @@ def _snake(rows, cols):
 
 
 def test_planted_cases_agree_with_check_mapping(smoke):
-    windows = harness.load_windows("cloud")
+    windows = harness.load_windows("cloud", "cloud")
     mesh = reference.mesh_adjacency(8, 16)
     unet = windows["unet"]
     free = np.ones(128, bool)
@@ -133,7 +133,7 @@ def test_mapping_exists_agrees_with_brute_force(seed):
 
 def test_odd_cycle_windows_have_no_mapping_and_paths_do():
     mesh = reference.mesh_adjacency(8, 16)
-    windows = harness.load_windows("cloud")
+    windows = harness.load_windows("cloud", "cloud")
     free = np.ones(128, bool)
     for name in ("nasnet", "pnasnet"):
         assert not reference.is_bipartite(windows[name].undirected())
