@@ -35,7 +35,10 @@ class MatchResult:
     feasible_count: int
     f_star: float
     f_star_trace: np.ndarray             # (T, K) global-best trajectory
-    all_mappings: np.ndarray             # (T*N, n, m) projected mappings
+    all_mappings: np.ndarray             # (T*N, n, m) projected mappings;
+                                         # (0, n, m) where none crossed
+                                         # (service Tier 0/1, and Tier 2
+                                         # on a single device)
     all_feasible: np.ndarray             # (T*N,)
     all_fitness: np.ndarray              # (T*N,)
     carry: Optional[tuple] = None        # (S_star, f_star, S_bar) warm-start
@@ -130,6 +133,39 @@ def collect_batch_results(outs, batch: int, orders=None, crops=None):
             slice_b,
             order=None if orders is None else orders[b],
             crop=None if crops is None else crops[b]))
+    return results
+
+
+def collect_served_results(host, batch: int, orders, crops):
+    """Host-side gather of a fetched ``pso.served_outs`` pytree into
+    per-problem ``MatchResult``s for its first ``batch`` slots.
+
+    The program has already selected each problem's served ``mapping``
+    (B, n, m), so this crops and un-permutes one (n, m) mapping per
+    problem; ``orders``/``crops`` are per problem. Mapping, counts and
+    scalars equal ``collect_result`` on the full trace. No trace crosses:
+    ``all_mappings`` is empty, (0, n, m) as the crop gives it, and
+    ``carry`` is left for the caller to attach.
+    """
+    results = []
+    for b in range(batch):
+        n, m = crops[b]
+        mapping = None
+        if host["found"][b]:
+            mapping = np.empty((n, m), np.uint8)
+            mapping[orders[b], :] = host["mapping"][b, :n, :m]
+        feas = host["feasible"][:, b].reshape(-1)
+        results.append(MatchResult(
+            mapping=mapping,
+            feasible_count=int(feas.sum()),
+            f_star=float(host["f_star"][b]),
+            f_star_trace=host["f_star_trace"][:, b],
+            all_mappings=np.zeros((0, n, m), np.uint8),
+            all_feasible=feas,
+            all_fitness=host["fitness"][:, b].reshape(-1),
+            epochs_run=int(host["epochs_run"][b]),
+            carry_verified=bool(host["carry_feasible"][b]),
+            prune_sweeps=int(host["prune_sweeps"][b])))
     return results
 
 

@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -689,30 +688,51 @@ def match(key: jax.Array, Q: jax.Array, G: jax.Array, mask: jax.Array,
     return _match_impl(key, Q, G, mask, cfg, carry0)
 
 
-def best_feasible(outs) -> Optional[jnp.ndarray]:
-    """Highest-fitness feasible mapping of an epoch trace, or None.
+def best_feasible(outs):
+    """Each problem's served mapping, selected from a batched epoch trace
+    on the device (jit-able).
 
-    The select runs on device: the feasibility flags and fitness values
-    stay resident, the winning row is picked with one masked argmax, and
-    only an any-feasible scalar plus that single (n, m) mapping cross to
-    the host — not the full (T·N, n, m) trace a per-leaf ``np.asarray``
-    used to move.
+    ``outs`` holds ``feasible``/``fitness`` (T, B, N) and ``mappings``
+    (T, B, N, n, m), as ``match_batch`` returns them, and optionally the
+    fast path's ``carry_mapping`` (B, n, m) and ``carry_feasible`` (B,).
+    Per problem the winner is the highest-fitness feasible particle over
+    all epochs, chosen by ``np.argmax``'s rule over the feasible entries
+    in epoch-major (T, N) order, as ``matcher.collect_result`` does: the
+    first among ties, a feasible particle at -inf still ahead of every
+    infeasible one, and the first feasible NaN ahead of everything. With
+    no feasible particle, a carry the fast path verified serves its
+    ``carry_mapping``.
+
+    Returns ``(mapping, found)``: (B, n, m) uint8, zero where nothing was
+    found, and (B,) bool.
     """
-    import numpy as np
-    feas = jnp.ravel(jnp.asarray(outs["feasible"]))
-    fit = jnp.ravel(jnp.asarray(outs["fitness"]))
-    maps = jnp.asarray(outs["mappings"])
-    maps = maps.reshape(-1, maps.shape[-2], maps.shape[-1])
-    # feasible entries rank by their own fitness (-inf fits clamped to
-    # the finite minimum so they still outrank every infeasible slot)
-    fmin = jnp.finfo(jnp.float32).min
-    score = jnp.where(feas,
-                      jnp.nan_to_num(fit.astype(jnp.float32),
-                                     neginf=fmin, posinf=jnp.finfo(
-                                         jnp.float32).max),
-                      -jnp.inf)
-    idx = jnp.argmax(score)
-    any_feasible, best = jax.device_get((feas.any(), maps[idx]))
-    if not bool(any_feasible):
-        return None
-    return np.asarray(best)
+    feas = jnp.asarray(outs["feasible"])
+    T, B, N = feas.shape
+    feas = jnp.swapaxes(feas, 0, 1).reshape(B, T * N)
+    fit = jnp.swapaxes(jnp.asarray(outs["fitness"], jnp.float32),
+                       0, 1).reshape(B, T * N)
+    nan = feas & jnp.isnan(fit)
+    top = jnp.max(jnp.where(feas, fit, -jnp.inf), axis=1, keepdims=True)
+    win = jnp.where(jnp.any(nan, axis=1, keepdims=True), nan,
+                    feas & (fit == top))
+    idx = jnp.argmax(win, axis=1)          # first winner
+    best = jnp.asarray(outs["mappings"])[idx // N, jnp.arange(B), idx % N]
+    found = jnp.any(feas, axis=1)
+    carry_ok = outs.get("carry_feasible")
+    if carry_ok is not None:
+        best = jnp.where((carry_ok & ~found)[:, None, None],
+                         outs["carry_mapping"], best)
+        found = found | carry_ok
+    return jnp.where(found[:, None, None], best, 0).astype(jnp.uint8), found
+
+
+def served_outs(outs):
+    """A ``match_batch`` output pytree as the service's swarm program
+    returns it: the per-particle trace ``mappings`` and the fast path's
+    ``carry_mapping`` give way to each problem's served ``mapping``
+    (B, n, m) and ``found`` (B,) from :func:`best_feasible`; every other
+    leaf passes through."""
+    outs = dict(outs)
+    outs["mapping"], outs["found"] = best_feasible(outs)
+    del outs["mappings"], outs["carry_mapping"]
+    return outs

@@ -94,7 +94,8 @@ from repro.core.graphs import (Graph, compatibility_mask,
 from repro.core.matcher import (MatchResult, build_distributed_match,
                                 build_distributed_match_batch,
                                 build_distributed_revalidate_batch,
-                                collect_batch_results, collect_result)
+                                collect_batch_results, collect_result,
+                                collect_served_results)
 from repro.core.preemptible_dag import pad_problem
 from repro.kernels import backend as kernel_backend
 from repro.kernels import pallas_compat
@@ -119,6 +120,12 @@ def _default_key() -> np.ndarray:
     key = np.asarray(jax.random.PRNGKey(0))
     key.flags.writeable = False
     return key
+
+
+def _tree_nbytes(tree) -> int:
+    """Payload bytes of a fetched host pytree."""
+    return int(sum(getattr(leaf, "nbytes", 0)
+                   for leaf in jax.tree_util.tree_leaves(tree)))
 
 
 def _round_up(v: int, mult: int) -> int:
@@ -228,6 +235,8 @@ class ServiceStats:
                                      # under the serial arm)
     host_bytes_transferred: int = 0  # payload bytes those fetches moved
     host_sync_wall_s: float = 0.0    # wall time spent blocked in fetches
+    tier2_fetch_bytes: int = 0       # payload bytes fetched for Tier-2
+                                     # swarm launches
     donated_launches: int = 0        # launches that donated their carry
                                      # input buffers to XLA
     tier0: TierStats = dataclasses.field(default_factory=TierStats)
@@ -701,10 +710,10 @@ class _LazyCarry:
 
     Slicing three device arrays out of the pool costs real dispatch
     time, and most callers never look at ``result.carry`` — so Tier-0
-    hits hand out this view instead. It retains the handle (pinning the
-    slab row even if the store evicts the entry later) and slices the
-    parts out only on first access; the reference drops when the view
-    is garbage-collected."""
+    hits and single-device Tier-2 results hand out this view instead.
+    It retains the handle (pinning the slab row even if the store evicts
+    the entry later) and slices the parts out only on first access; the
+    reference drops when the view is garbage-collected."""
 
     __slots__ = ("_handle", "_parts")
 
@@ -1002,7 +1011,7 @@ class MatcherService:
         and snapshots from a process whose digest differs are ignored."""
         return kernel_backend.config_digest(
             self.cfg,
-            extra=("svc-v2", jax.__version__, jax.default_backend(),
+            extra=("svc-v3", jax.__version__, jax.default_backend(),
                    self.n_multiple, self.m_multiple, self.batch_classes,
                    self.mesh is not None))
 
@@ -1086,15 +1095,17 @@ class MatcherService:
         """One swarm executable per (shape bucket, padded batch class),
         jitted as ``immsched_swarm_batch`` (module
         ``jit_immsched_swarm_batch``; ``jit_call_exported`` once
-        restored from the AOT cache)."""
+        restored from the AOT cache). On a single device it selects each
+        problem's served mapping in the program (``pso.served_outs``) and
+        returns no particle trace; the mesh program returns the trace."""
         def build():
             if self.mesh is None:
                 cfg = self.cfg
 
                 def immsched_swarm_batch(keys, Qb, Gb, maskb, carry0,
                                          _cfg=cfg):
-                    return pso._match_batch_body(keys, Qb, Gb, maskb, _cfg,
-                                                 carry0)
+                    return pso.served_outs(pso._match_batch_body(
+                        keys, Qb, Gb, maskb, _cfg, carry0))
 
                 return jax.jit(immsched_swarm_batch,
                                donate_argnums=self._donate_argnums("batch"))
@@ -1409,9 +1420,7 @@ class MatcherService:
             host = jax.device_get(tree)
             self.stats.host_syncs += 1
             self.stats.host_sync_wall_s += time.perf_counter() - t0
-        self.stats.host_bytes_transferred += int(sum(
-            getattr(leaf, "nbytes", 0)
-            for leaf in jax.tree_util.tree_leaves(host)))
+        self.stats.host_bytes_transferred += _tree_nbytes(host)
         return host
 
     def _fetch_tree(self, rec: "_LaunchRecord"):
@@ -1419,13 +1428,21 @@ class MatcherService:
         reads on host. Tier-0 revalidation never looks at the rebased
         ``S*``/``S̄`` planes host-side (hit carries stay pooled on
         device), so skipping them keeps the biggest leaves out of every
-        warm fetch. Swarm and mesh launches fetch everything."""
-        if rec.kind != "reval" or self.mesh is not None:
+        warm fetch. A swarm launch fetches each problem's served mapping,
+        the (T, B, N) flags and fitness, the f* trace and the
+        per-problem scalars: its S*/S̄ planes stay in the pool. Mesh
+        launches fetch everything."""
+        if self.mesh is not None:
             return rec.outs
-        keys = (("mapping", "ok", "f_carry", "prune_sweeps")
-                if rec.tier == 0 else
-                ("mapping", "ok_rebase", "fitness", "S_star", "S_bar",
-                 "prune_sweeps"))
+        if rec.kind == "swarm":
+            keys = ("mapping", "found", "feasible", "fitness",
+                    "f_star_trace", "f_star", "epochs_run",
+                    "carry_feasible", "prune_sweeps")
+        elif rec.tier == 0:
+            keys = ("mapping", "ok", "f_carry", "prune_sweeps")
+        else:
+            keys = ("mapping", "ok_rebase", "fitness", "S_star", "S_bar",
+                    "prune_sweeps")
         return {k: rec.outs[k] for k in keys}
 
     @staticmethod
@@ -1547,8 +1564,8 @@ class MatcherService:
 
         # the controller state stays device-resident for the store; the
         # result itself resolves through ONE counted blocking fetch
-        base = collect_result(self._sync_fetch(outs), order=order,
-                              crop=(n, m))
+        host = self._sync_fetch(outs)
+        base = collect_result(host, order=order, crop=(n, m))
         res = ServiceMatchResult(**{f.name: getattr(base, f.name)
                                     for f in dataclasses.fields(MatchResult)})
         if self.warm_start:
@@ -1567,6 +1584,7 @@ class MatcherService:
             res.tier = 0
         else:
             self.stats.tier2.launches += 1
+            self.stats.tier2_fetch_bytes += _tree_nbytes(host)
             self.stats.epoch_fused_launches += 1
             self.stats.epoch_finish_launches += 1
             self.stats.epoch_finish_problems += 1
@@ -2013,7 +2031,8 @@ class MatcherService:
         items: dispatch, then a blocking fetch of just this launch's
         outputs (the one-sync-per-launch baseline arm)."""
         rec = self._dispatch_swarm(bucket, items)
-        self._apply_swarm(rec, self._sync_fetch(rec.outs, rec.drain))
+        self._apply_swarm(rec, self._sync_fetch(self._fetch_tree(rec),
+                                                rec.drain))
 
     def _dispatch_swarm(self, bucket, items: List[_PipelineItem]
                         ) -> _LaunchRecord:
@@ -2092,20 +2111,26 @@ class MatcherService:
         future warm starts."""
         with _span("apply", tier=2, drain=rec.drain):
             items, B, padded = rec.items, rec.B, rec.padded
-            batch_results = collect_batch_results(
-                host, rec.bclass,
-                orders=[r.order for r in padded],
-                crops=[r.crop for r in padded])
-            done = time.perf_counter()
-            # every item's controller state is stored for future warm
-            # starts: on a single-device service all B rows in one
-            # program, straight from the device outputs
-            if self.warm_start and self.mesh is None:
+            self.stats.tier2_fetch_bytes += _tree_nbytes(host)
+            orders = [r.order for r in padded]
+            crops = [r.crop for r in padded]
+            if self.mesh is None:
+                # the program selected each served mapping; every item's
+                # controller state goes to the pool, all B rows in one
+                # program straight from the device outputs, and its
+                # result carries a lazy view of its row
+                batch_results = collect_served_results(host, B, orders,
+                                                       crops)
                 stored = self._pool.write_back(
                     rec.outs["S_star"], rec.outs["f_star"],
                     rec.outs["S_bar"], range(B))
+                for r, h in zip(batch_results, stored):
+                    r.carry = _LazyCarry(h)
             else:
+                batch_results = collect_batch_results(host, rec.bclass,
+                                                      orders, crops)
                 stored = [r.carry for r in batch_results[:B]]
+            done = time.perf_counter()
 
             for j, it in enumerate(items):
                 base = batch_results[j]
@@ -2211,6 +2236,7 @@ class MatcherService:
             "host_syncs_per_drain": s.host_syncs_per_drain,
             "host_bytes_transferred": s.host_bytes_transferred,
             "host_sync_wall_s": s.host_sync_wall_s,
+            "tier2_fetch_bytes": s.tier2_fetch_bytes,
             "donated_launches": s.donated_launches,
             "pool_puts": self._pool.puts,
             "pool_writes": self._pool.writes,

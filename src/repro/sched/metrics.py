@@ -118,16 +118,17 @@ def frontend_stats(result: SimResult) -> Dict[str, float]:
 def transfer_stats(result: SimResult) -> Dict[str, float]:
     """Host-sync census of one run's matcher service (the
     device-resident drain pipeline): drain rounds, blocking device→host
-    fetches with their payload bytes and blocked wall time, launches
-    that donated their carry buffers, and device-carry-pool activity.
+    fetches with their payload bytes and blocked wall time, the bytes
+    Tier-2 swarm launches fetched, launches that donated their carry
+    buffers, and device-carry-pool activity.
     ``host_syncs_per_drain`` is the pipeline's budget observable — ~1 on
     all-warm drain traffic. Keys default to 0 for analytic runs that
     never touch a live service."""
     ms = result.matcher_stats
     keys = ("drains", "host_syncs", "host_syncs_per_drain",
             "host_bytes_transferred", "host_sync_wall_s",
-            "donated_launches", "pool_puts", "pool_writes",
-            "pool_gathers", "pool_live_rows")
+            "tier2_fetch_bytes", "donated_launches", "pool_puts",
+            "pool_writes", "pool_gathers", "pool_live_rows")
     return {k: ms.get(k, 0) for k in keys}
 
 

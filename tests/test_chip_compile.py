@@ -1,4 +1,5 @@
-"""The served Pallas kernels compile for a TPU v5e, with no chip attached.
+"""The served Pallas kernels, and the Tier-2 program around them, compile
+for a TPU v5e, with no chip attached.
 
 The TPU compiler is installed with JAX and compiles for a described
 ``v5e:2x2`` topology, so Mosaic's refusals (unsupported casts or
@@ -105,3 +106,26 @@ def test_revalidate_program_compiles_for_v5e(one_chip):
         one_chip, ((B, ROWS, ROWS), u8), ((B, COLS, COLS), u8),
         ((B, ROWS, COLS), u8), *carry)
     assert text.count("tpu_custom_call") >= 2
+
+
+def test_served_swarm_program_compiles_for_v5e(one_chip):
+    """The service's Tier-2 program for a batch of 8, as
+    ``MatcherService`` builds it: the swarm plus the on-device selection
+    of each problem's served mapping, with no particle trace among its
+    outputs."""
+    cfg = pso.PSOConfig(quantized=True, backend="pallas", early_exit=True)
+    B = 8
+    key = jax.eval_shape(lambda: jax.random.split(jax.random.PRNGKey(0), B))
+
+    def program(keys, Q, G, mk, S, f, C):
+        return pso.served_outs(pso._match_batch_body(keys, Q, G, mk, cfg,
+                                                     (S, f, C)))
+
+    carry = ((B, ROWS, COLS), f32), ((B,), f32), ((B, ROWS, COLS), f32)
+    shapes = (((key.shape, key.dtype),) + (((B, ROWS, ROWS), u8),
+              ((B, COLS, COLS), u8), ((B, ROWS, COLS), u8)) + carry)
+    _compile(program, one_chip, *shapes)
+    outs = jax.eval_shape(program, *(jax.ShapeDtypeStruct(s, d)
+                                     for s, d in shapes))
+    assert outs["mapping"].shape == (B, ROWS, COLS)
+    assert "mappings" not in outs

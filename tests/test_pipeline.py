@@ -1,8 +1,9 @@
 """Device-resident drain pipeline: the host-sync census (one blocking
 fetch per all-warm drain), pipelined-vs-serial bitwise parity, the
 pooled carry path's program budget, carry buffer donation, the device
-carry pool's row lifecycle, the pooled popcount index bookkeeping, and
-device-side best-feasible selection."""
+carry pool's row lifecycle, the pooled popcount index bookkeeping,
+device-side selection of the served mapping and the Tier-2 fetch's
+payload."""
 import functools
 
 import numpy as np
@@ -12,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import graphs, pso
+from repro.core.matcher import collect_batch_results, collect_served_results
 from repro.core.service import (CarryStore, DeviceCarryPool, MatcherService,
                                 ServiceStats)
 from repro.kernels import pallas_compat
@@ -450,10 +452,11 @@ def test_sim_popcount_computed_once_at_ingest():
 
 
 # ---------------------------------------------------------------------------
-# device-side best_feasible
+# device-side selection of the served mapping (pso.best_feasible)
 # ---------------------------------------------------------------------------
 
 def _outs(feasible, fitness, maps):
+    """A (T, B, N) trace with the problem axis B after the epoch axis."""
     return {"feasible": jnp.asarray(feasible),
             "fitness": jnp.asarray(fitness, jnp.float32),
             "mappings": jnp.asarray(maps, jnp.uint8)}
@@ -461,32 +464,211 @@ def _outs(feasible, fitness, maps):
 
 def test_best_feasible_matches_numpy_oracle():
     rng = np.random.default_rng(0)
+    T, B, N = 2, 3, 4
     for _ in range(10):
-        P = 6
-        feas = rng.random(P) < 0.5
-        fit = rng.standard_normal(P).astype(np.float32)
-        maps = rng.integers(0, 2, (P, 3, 5)).astype(np.uint8)
-        got = pso.best_feasible(_outs(feas, fit, maps))
-        if not feas.any():
-            assert got is None
-            continue
-        idx = np.where(feas)[0]
-        want = maps[idx[np.argmax(fit[idx])]]
-        np.testing.assert_array_equal(np.asarray(got), want)
+        feas = rng.random((T, B, N)) < 0.3
+        fit = rng.standard_normal((T, B, N)).astype(np.float32)
+        maps = rng.integers(0, 2, (T, B, N, 3, 5)).astype(np.uint8)
+        got, found = pso.best_feasible(_outs(feas, fit, maps))
+        for b in range(B):
+            f_b = feas[:, b].reshape(-1)
+            assert bool(found[b]) == f_b.any()
+            if not f_b.any():
+                np.testing.assert_array_equal(np.asarray(got[b]), 0)
+                continue
+            idx = np.where(f_b)[0]
+            want = maps[:, b].reshape(-1, 3, 5)[
+                idx[np.argmax(fit[:, b].reshape(-1)[idx])]]
+            np.testing.assert_array_equal(np.asarray(got[b]), want)
 
 
 def test_best_feasible_neginf_feasible_still_wins():
     """A feasible particle at f=-inf must beat infeasible slots (the
     masked score floor cannot shadow real entries)."""
     maps = np.stack([np.eye(3, 5, dtype=np.uint8) * i for i in range(3)])
-    got = pso.best_feasible(_outs(
-        [False, True, False], [1.0, -np.inf, 2.0], maps))
-    np.testing.assert_array_equal(np.asarray(got), maps[1])
+    got, found = pso.best_feasible(_outs(
+        [[[False, True, False]]], [[[1.0, -np.inf, 2.0]]], maps[None, None]))
+    assert bool(found[0])
+    np.testing.assert_array_equal(np.asarray(got[0]), maps[1])
 
 
 def test_best_feasible_none_when_infeasible():
-    maps = np.zeros((2, 3, 5), np.uint8)
-    assert pso.best_feasible(_outs([False, False], [0.0, 1.0], maps)) is None
+    maps = np.ones((1, 1, 2, 3, 5), np.uint8)
+    got, found = pso.best_feasible(_outs([[[False, False]]], [[[0.0, 1.0]]],
+                                         maps))
+    assert not bool(found[0])
+    np.testing.assert_array_equal(np.asarray(got[0]), 0)
+
+
+def _batch_trace(rng, T=3, B=3, N=5, n=4, m=6, K=2, p_feas=0.3):
+    """A full ``match_batch`` output pytree of random values."""
+    return {
+        "mappings": rng.integers(0, 2, (T, B, N, n, m)).astype(np.uint8),
+        "feasible": rng.random((T, B, N)) < p_feas,
+        "fitness": rng.standard_normal((T, B, N)).astype(np.float32),
+        "f_star_trace": rng.standard_normal((T, B, K)).astype(np.float32),
+        "S_star": rng.random((B, n, m)).astype(np.float32),
+        "f_star": rng.standard_normal(B).astype(np.float32),
+        "S_bar": rng.random((B, n, m)).astype(np.float32),
+        "epochs_run": rng.integers(0, T + 1, B).astype(np.int32),
+        "carry_mapping": rng.integers(0, 2, (B, n, m)).astype(np.uint8),
+        "carry_feasible": np.zeros(B, bool),
+        "prune_sweeps": rng.integers(0, 5, B).astype(np.int32),
+    }
+
+
+def _selection_case(case):
+    """(trace, problems collected, orders, crops) of one exactness case."""
+    rng = np.random.default_rng(sorted(_SELECTION_CASES).index(case))
+    outs = _batch_trace(rng)
+    T, B, N, n, m = outs["mappings"].shape
+    feas, fit = outs["feasible"], outs["fitness"]
+    batch = B
+    orders = [np.arange(n)] * B
+    crops = [(n, m)] * B
+    if case == "ties":
+        feas[:] = rng.random(feas.shape) < 0.6
+        fit[:] = np.round(fit)            # many equal fitness values
+        fit[1, 0, :] = fit.max() + 1.0    # epoch 1 ties across particles
+    elif case == "neginf_feasible":
+        feas[:] = False
+        feas[2, 0, 3] = feas[1, 1, 0] = feas[2, 1, 1] = True
+        fit[:, :2] = -np.inf
+        fit[2, 1, 1] = -np.inf
+    elif case == "nan_fitness":
+        feas[:] = True
+        fit[0, 0, 4] = fit[2, 0, 1] = np.nan     # first NaN wins
+        fit[1, 1, 2] = np.nan
+        feas[0, 2, 0] = False
+        fit[0, 2, 0] = np.nan                    # infeasible NaN ignored
+    elif case == "all_infeasible":
+        feas[:] = False
+    elif case == "carry_fastpath":
+        feas[:, :2] = False
+        outs["carry_feasible"][:] = [True, False, True]
+    elif case == "pad_slots":
+        outs = _batch_trace(rng, B=8)
+        outs["carry_feasible"][3:] = True        # pre-finished pad slots
+        outs["feasible"][:, 3:] = False
+        batch = 3
+        orders, crops = [np.arange(n)] * 8, [(n, m)] * 8
+    elif case == "crops_orders":
+        crops = [(3, 5), (4, 4), (2, 6)]
+        orders = [rng.permutation(c[0]) for c in crops]
+        outs["carry_feasible"][2] = True
+        feas[:, 2] = False
+    return outs, batch, orders, crops
+
+
+_SELECTION_CASES = ("random", "ties", "neginf_feasible", "nan_fitness",
+                    "all_infeasible", "carry_fastpath", "pad_slots",
+                    "crops_orders")
+
+
+@pytest.mark.parametrize("case", _SELECTION_CASES)
+def test_served_selection_equals_collect_result(case):
+    """The swarm program's on-device choice of each problem's served
+    mapping, collected from the fetched subset, equals
+    ``collect_result`` on the full trace: mapping, found, counts,
+    scalars and the (T·N,) flags and fitness."""
+    outs, batch, orders, crops = _selection_case(case)
+    want = collect_batch_results(outs, outs["f_star"].shape[0],
+                                 orders=orders, crops=crops)[:batch]
+    served = jax.device_get(jax.jit(pso.served_outs)(outs))
+    assert "mappings" not in served and "carry_mapping" not in served
+    got = collect_served_results(served, batch, orders, crops)
+    assert len(got) == batch
+    for g, w, (n, m) in zip(got, want, crops):
+        assert g.found == w.found
+        if w.found:
+            np.testing.assert_array_equal(g.mapping, w.mapping)
+            assert g.mapping.dtype == w.mapping.dtype
+        assert g.feasible_count == w.feasible_count
+        assert g.epochs_run == w.epochs_run
+        assert g.carry_verified == w.carry_verified
+        assert g.prune_sweeps == w.prune_sweeps
+        assert g.f_star == w.f_star
+        np.testing.assert_array_equal(g.f_star_trace, w.f_star_trace)
+        np.testing.assert_array_equal(g.all_feasible, w.all_feasible)
+        np.testing.assert_array_equal(g.all_fitness, w.all_fitness)
+        assert g.all_mappings.shape == (0, n, m)
+
+
+def test_cold_drain_serves_what_match_batch_selects():
+    """A cold two-bucket drain serves, per problem, the mapping, found
+    flag, f*, epochs and feasible count that ``collect_batch_results``
+    picks from ``pso.match_batch``'s full trace for the same keys."""
+    groups = {(6, 12): [0, 1], (5, 24): [2]}     # batch classes 2 and 1
+    svc = MatcherService(CFG)
+    for (n, m), seeds in groups.items():
+        for s in seeds:
+            q, g = _planted(s, n, m)
+            svc.submit(q, g, key=np.asarray(jax.random.PRNGKey(50 + s)))
+    served = svc.drain()
+    assert all(r.tier == 2 for r in served)
+    want = []
+    for (n, m), seeds in groups.items():
+        reqs = [svc._prepare(*_planted(s, n, m), None, None) for s in seeds]
+        outs = pso.match_batch(
+            np.stack([np.asarray(jax.random.PRNGKey(50 + s))
+                      for s in seeds]),
+            np.stack([r.Qp for r in reqs]), np.stack([r.Gp for r in reqs]),
+            np.stack([r.maskp for r in reqs]), svc.cfg)
+        want += collect_batch_results(outs, len(seeds),
+                                      orders=[r.order for r in reqs],
+                                      crops=[r.crop for r in reqs])
+    assert any(w.found for w in want)
+    for g, w in zip(served, want):
+        assert g.found == w.found
+        if w.found:
+            np.testing.assert_array_equal(g.mapping, w.mapping)
+        assert g.f_star == w.f_star
+        assert g.epochs_run == w.epochs_run
+        assert g.feasible_count == w.feasible_count
+        assert g.all_mappings.shape == (0, *w.all_mappings.shape[1:])
+
+
+def _swarm_fetches(svc, monkeypatch, specs):
+    """(bucket, bclass, leaf shapes and dtypes, fetched bytes) of every
+    swarm launch a drain of ``specs`` applies."""
+    seen = []
+    apply = svc._apply_swarm
+
+    def spy(rec, host):
+        seen.append((rec.bucket, rec.bclass,
+                     {k: (v.shape, v.dtype) for k, v in host.items()},
+                     sum(v.nbytes for v in host.values())))
+        apply(rec, host)
+
+    monkeypatch.setattr(svc, "_apply_swarm", spy)
+    _burst(svc, specs)
+    return seen
+
+
+def test_swarm_fetch_moves_served_subset_only(monkeypatch):
+    """A cold Tier-2 drain fetches no per-particle n-by-m plane and no
+    S*/S̄ plane, at most bclass × (n·m + 2 KiB) bytes a launch, counted
+    by ``tier2_fetch_bytes``; both drain arms fetch the same subset."""
+    specs = [(s, n, m) for n, m in BUCKET_ARGS for s in range(3)]
+    arms = []
+    for pipelined in (True, False):
+        svc = MatcherService(CFG, pipelined=pipelined)
+        before = svc.stats_dict()
+        seen = _swarm_fetches(svc, monkeypatch, specs)
+        after = svc.stats_dict()
+        assert seen
+        assert after["tier2_fetch_bytes"] - before["tier2_fetch_bytes"] \
+            == sum(nb for *_, nb in seen)
+        assert after["tier2_launches"] - before["tier2_launches"] \
+            == len(seen)
+        for (n, m), bclass, leaves, nbytes in seen:
+            assert "S_star" not in leaves and "S_bar" not in leaves
+            for shape, dtype in leaves.values():
+                if shape[-2:] == (n, m):
+                    assert shape == (bclass, n, m) and dtype == np.uint8
+            assert nbytes <= bclass * (n * m + 2048)
+        arms.append(seen)
+    assert arms[0] == arms[1]
 
 
 # ---------------------------------------------------------------------------
