@@ -2,7 +2,7 @@
 
 A restarted ``MatcherService`` process used to pay the full cold path on
 its very first arrival — a Python-level jit trace (seconds), an XLA
-compile, and a cold :class:`~repro.core.service.CarryStore` — exactly the
+compile, and a cold :class:`~repro.core.carries.CarryStore` — exactly the
 unpredictable-arrival case the paper bounds scheduling latency for. This
 module removes both cold components:
 

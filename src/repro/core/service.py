@@ -14,7 +14,8 @@ when tasks arrive unpredictably at microsecond granularity. The
     an LRU of ``cache_capacity`` entries; evicting an entry drops its
     executable. Repeat arrivals never recompile.
   * **Warm starts** — the final global-controller state ``(S*, f*, S̄)`` of
-    each call is remembered in a two-level :class:`CarryStore`: an *exact*
+    each call is remembered in a two-level :class:`CarryStore` (the carry
+    path lives in :mod:`repro.core.carries`): an *exact*
     content-keyed LRU plus a *similarity* index keyed by
     (query digest, bucket, free-engine signature) for platform-state
     drift.
@@ -86,9 +87,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from repro.accel.target_graph import signature_bits
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import persist, pso
+from repro.core.carries import (CarryPath, CarryStore, carry_tuple,
+                                release, result_view, retain)
 from repro.core.graphs import (Graph, compatibility_mask,
                                topological_relabel)
 from repro.core.matcher import (MatchResult, build_distributed_match,
@@ -126,6 +128,11 @@ def _tree_nbytes(tree) -> int:
     """Payload bytes of a fetched host pytree."""
     return int(sum(getattr(leaf, "nbytes", 0)
                    for leaf in jax.tree_util.tree_leaves(tree)))
+
+
+def _chunks(seq: List, size: int) -> List[List]:
+    """``seq`` cut into consecutive launches of at most ``size``."""
+    return [seq[i:i + size] for i in range(0, len(seq), size)]
 
 
 def _round_up(v: int, mult: int) -> int:
@@ -359,530 +366,13 @@ class _LaunchRecord:
     drain: Optional[int] = None      # front-end drain number (span tag)
 
 
-class CarryStore:
-    """Two-level warm-start store for the tiered pipeline.
-
-    * **exact** — LRU of full content keys (workload key + shapes + a
-      digest of Qp/Gp/maskp): a hit means *this exact problem* was solved
-      before; its carry feeds Tier 0.
-    * **similarity** — LRU keyed by ``(query digest, bucket, engine
-      signature)``: entries describe *which platform state* a carry was
-      produced on. ``nearest`` returns the stored carry whose free-engine
-      bitmask overlaps the query's the most (ties go to the most recently
-      stored), feeding Tier 1 rebases under fragmentation drift.
-
-    ``nearest`` probes a **popcount-bucketed index**: entries of one
-    (query digest, bucket) group are binned by the popcount of their
-    free-engine bitmask, and bins are visited in decreasing order of the
-    best overlap they could possibly hold (``min(pop, query_pop)``),
-    stopping as soon as the bound cannot beat the best hit found — at
-    thousands of stored platform states the probe touches a handful of
-    bins instead of scanning the store. The exhaustive linear scan is
-    kept as ``_nearest_linear`` (``sim_index=False`` fallback, and the
-    oracle the index is property-tested against).
-
-    Popcounts are computed ONCE on host numpy when an entry is ingested
-    (``_sim_pop``) — ``put``/``nearest`` never reduce a bit vector per
-    stored entry again, so no store operation can turn into a per-entry
-    device sync however the bits arrive.
-
-    The store is payload-agnostic (tests store plain ints), but it
-    participates in device-carry lifetime management: any stored value
-    exposing ``retain``/``release`` (the service's
-    :class:`DeviceCarryPool` handles) is retained on insert and released
-    when it is overwritten or evicted, so slab rows are reclaimed the
-    moment no store references them.
-    """
-
-    def __init__(self, capacity: int, sim_capacity: int,
-                 stats: ServiceStats, sim_index: bool = True):
-        self.capacity = max(int(capacity), 1)
-        self.sim_capacity = max(int(sim_capacity), 1)
-        self.stats = stats
-        self.sim_index = bool(sim_index)
-        self._exact: "OrderedDict[Tuple, tuple]" = OrderedDict()
-        self._sim: "OrderedDict[Tuple, Tuple[np.ndarray, tuple]]" = \
-            OrderedDict()
-        # recency sequence per similarity key (== iteration order of
-        # ``_sim``): the index's explicit most-recent-wins tiebreaker
-        self._sim_seq: Dict[Tuple, int] = {}
-        self._seq = 0
-        # (qdigest, bucket, bit-length) -> {popcount: OrderedDict[sig]}
-        self._sim_buckets: Dict[Tuple, Dict[int, "OrderedDict[bytes, None]"]] \
-            = {}
-        # per-entry popcount, computed once at ingest (host numpy)
-        self._sim_pop: Dict[Tuple, int] = {}
-
-    def __len__(self) -> int:
-        return len(self._exact)
-
-    @property
-    def sim_entries(self) -> int:
-        """Number of entries currently in the similarity store."""
-        return len(self._sim)
-
-    @staticmethod
-    def _retain(carry) -> None:
-        r = getattr(carry, "retain", None)
-        if callable(r):
-            r()
-
-    @staticmethod
-    def _release(carry) -> None:
-        r = getattr(carry, "release", None)
-        if callable(r):
-            r()
-
-    def clear(self) -> None:
-        """Drop both stores and the derived popcount index/recency,
-        releasing every device-pool carry they referenced."""
-        for c in self._exact.values():
-            self._release(c)
-        for _, c in self._sim.values():
-            self._release(c)
-        self._exact.clear()
-        self._sim.clear()
-        self._sim_seq.clear()
-        self._sim_buckets.clear()
-        self._sim_pop.clear()
-
-    # -- exact tier --------------------------------------------------------
-
-    def get(self, key) -> Tuple[Optional[tuple], bool]:
-        """Exact-store lookup → ``(carry, hit)``; refreshes LRU recency
-        and counts ``warm_hits``/``warm_misses``."""
-        if key in self._exact:
-            self._exact.move_to_end(key)
-            self.stats.warm_hits += 1
-            return self._exact[key], True
-        self.stats.warm_misses += 1
-        return None, False
-
-    def put(self, key, carry) -> None:
-        """Store ``carry`` (a ``(S*, f*, S̄)`` tuple of (n, m)/scalar/
-        (n, m) arrays, or a device-pool handle of one) under the exact
-        content key, evicting LRU entries beyond ``capacity``."""
-        old = self._exact.get(key)
-        if old is not None and old is not carry:
-            self._release(old)
-        if old is not carry:
-            self._retain(carry)
-        self._exact[key] = carry
-        while len(self._exact) > self.capacity:
-            _, evicted = self._exact.popitem(last=False)
-            self._release(evicted)
-            self.stats.warm_evictions += 1
-
-    # -- similarity tier ---------------------------------------------------
-
-    @staticmethod
-    def _bits(sig: bytes) -> np.ndarray:
-        return np.asarray(signature_bits(sig))
-
-    def put_similar(self, qdigest: str, bucket: Tuple[int, int],
-                    sig: bytes, carry) -> None:
-        """Store ``carry`` under the similarity key (query digest, shape
-        bucket, free-engine signature) and index it by signature
-        popcount (computed once, at ingest); refreshes recency for
-        most-recent-wins ``nearest`` tiebreaks."""
-        key = (qdigest, bucket, sig)
-        bits = self._bits(sig)
-        prev = self._sim.get(key)
-        fresh = prev is None
-        if not fresh and prev[1] is not carry:
-            self._release(prev[1])
-        if fresh or prev[1] is not carry:
-            self._retain(carry)
-        self._sim[key] = (bits, carry)
-        self._sim.move_to_end(key)
-        self._seq += 1
-        self._sim_seq[key] = self._seq
-        if fresh:
-            pc = int(bits.sum())
-            self._sim_pop[key] = pc
-            group = self._sim_buckets.setdefault(
-                (qdigest, bucket, bits.shape[0]), {})
-            group.setdefault(pc, OrderedDict())[sig] = None
-        while len(self._sim) > self.sim_capacity:
-            old_key, (old_bits, old_carry) = self._sim.popitem(last=False)
-            self._drop_sim_key(old_key, old_bits)
-            self._release(old_carry)
-            self.stats.sim_evictions += 1
-
-    def _drop_sim_key(self, key: Tuple, bits: np.ndarray) -> None:
-        """Remove an evicted similarity entry from the popcount index
-        (``bits``: the entry's already-unpacked bit vector; the entry's
-        popcount comes from the ingest-time cache, not a recount)."""
-        qd, bk, sig = key
-        self._sim_seq.pop(key, None)
-        pc = self._sim_pop.pop(key, None)
-        gkey = (qd, bk, bits.shape[0])
-        group = self._sim_buckets.get(gkey)
-        if group is None:
-            return
-        if pc is None:  # pragma: no cover - pre-index entries
-            pc = int(bits.sum())
-        bin_ = group.get(pc)
-        if bin_ is not None:
-            bin_.pop(sig, None)
-            if not bin_:
-                del group[pc]
-        if not group:
-            del self._sim_buckets[gkey]
-
-    def nearest(self, qdigest: str, bucket: Tuple[int, int], sig: bytes,
-                exclude_sig: Optional[bytes] = None
-                ) -> Optional[Tuple[bytes, tuple]]:
-        """Stored carry of the platform state nearest to ``sig``.
-
-        Nearest = max popcount of the AND of the free-engine bitmasks;
-        ties broken toward the smaller symmetric difference, then toward
-        the most recently stored entry. Returns ``(stored_sig, carry)``
-        or None when no same-workload entry overlaps at all. Served from
-        the popcount-bucketed index (identical results to
-        ``_nearest_linear`` — property-tested) unless ``sim_index`` is
-        off.
-        """
-        if not self.sim_index:
-            return self._nearest_linear(qdigest, bucket, sig, exclude_sig)
-        bits = self._bits(sig)
-        qpop = int(bits.sum())
-        group = self._sim_buckets.get((qdigest, bucket, bits.shape[0]))
-        if not group or qpop == 0:
-            return None
-
-        def upper_bound(pc: int) -> Tuple[int, int]:
-            # best (overlap, -symdiff) any popcount-pc bitmask can score
-            ov = min(pc, qpop)
-            return ov, -(pc + qpop - 2 * ov)
-
-        best = None
-        best_score = (0, float("-inf"), -1)     # (overlap, -symdiff, seq)
-        for pc in sorted(group, key=upper_bound, reverse=True):
-            ub = upper_bound(pc)
-            if ub[0] <= 0 or ub < (best_score[0], best_score[1]):
-                break        # bins are bound-sorted: nothing below can win
-            for s in group[pc]:
-                if s == exclude_sig:
-                    continue
-                key = (qdigest, bucket, s)
-                b, carry = self._sim[key]
-                overlap = int((b & bits).sum())
-                if overlap <= 0:
-                    continue
-                score = (overlap, -int((b ^ bits).sum()),
-                         self._sim_seq[key])
-                if score > best_score:
-                    best_score = score
-                    best = (s, carry)
-        return best
-
-    # -- snapshot support --------------------------------------------------
-
-    def export_state(self) -> Tuple[List[Tuple[Tuple, tuple]],
-                                    List[Tuple[Tuple, tuple]]]:
-        """Both stores as ``(exact_items, sim_items)`` key/carry lists.
-
-        Items come out in LRU order (least recent first) so an
-        ``import_state`` replay reproduces recency — evictions and
-        ``nearest`` most-recent-wins tiebreaks behave identically after
-        a snapshot/restore round trip. Carries are returned as stored
-        (device or host arrays); the snapshot writer converts to numpy.
-        """
-        exact = [(k, c) for k, c in self._exact.items()]
-        sim = [(k, c) for k, (_, c) in self._sim.items()]
-        return exact, sim
-
-    def import_state(self, exact_items, sim_items) -> Tuple[int, int]:
-        """Replay exported items into this (fresh) store, oldest first.
-
-        Uses the normal ``put``/``put_similar`` paths so the similarity
-        popcount index and recency sequence are rebuilt from scratch —
-        the snapshot never persists derived index structures, only the
-        keys and carries. Returns ``(n_exact, n_sim)`` loaded. Entries
-        beyond this store's capacities age out exactly as live puts
-        would."""
-        for k, c in exact_items:
-            self.put(k, c)
-        for (qdigest, bucket, sig), c in sim_items:
-            self.put_similar(qdigest, bucket, sig, c)
-        return len(exact_items), len(sim_items)
-
-    def _nearest_linear(self, qdigest: str, bucket: Tuple[int, int],
-                        sig: bytes, exclude_sig: Optional[bytes] = None
-                        ) -> Optional[Tuple[bytes, tuple]]:
-        """Exhaustive-scan fallback (and the index's test oracle)."""
-        bits = self._bits(sig)
-        best = None
-        best_score = (0, float("-inf"))
-        for (qd, bk, s), (b, carry) in self._sim.items():
-            if qd != qdigest or bk != bucket or s == exclude_sig:
-                continue
-            if b.shape != bits.shape:
-                continue
-            overlap = int((b & bits).sum())
-            if overlap <= 0:
-                continue
-            score = (overlap, -int((b ^ bits).sum()))
-            if score >= best_score:     # >=: most recent wins ties
-                best_score = score
-                best = (s, carry)
-        return best
-
-
-def carry_write_back(Sb, fb, Cb, S, f, C, idx):
-    """Write launch outputs into pool slabs in place. ``idx`` is (2, L)
-    int32 over the L output slots: row 0 the destination slab row (the
-    slab capacity, out of range, for a slot that stores nothing — the
-    scatter drops it), row 1 a flag storing f* = -inf instead of the
-    slot's f (a rebased Tier-2 seed)."""
-    rows, seed = idx[0], idx[1] != 0
-    f = jnp.where(seed, -jnp.inf, f.astype(jnp.float32))
-    return (Sb.at[rows].set(S.astype(jnp.float32), mode="drop"),
-            fb.at[rows].set(f, mode="drop"),
-            Cb.at[rows].set(C.astype(jnp.float32), mode="drop"))
-
-
-def carry_assemble(Sb, fb, Cb, idx, maskb):
-    """One launch's stacked ``(S*, f*, S̄)`` inputs: slot b takes slab row
-    ``idx[b]``, or the cold prior of ``maskb[b]`` where ``idx[b] < 0``.
-    The result is freshly allocated, so the launch may donate it."""
-    cold = idx < 0
-    rows = jnp.maximum(idx, 0)
-    S0, f0, C0 = pso.default_carry_batch(maskb)
-    c3 = cold[:, None, None]
-    return (jnp.where(c3, S0, jnp.take(Sb, rows, axis=0)),
-            jnp.where(cold, f0, jnp.take(fb, rows, axis=0)),
-            jnp.where(c3, C0, jnp.take(Cb, rows, axis=0)))
-
-
-# one program per (slab capacity, n, m, batch class): the slabs are
-# donated, so a write never doubles their footprint
-_carry_write_back = jax.jit(carry_write_back, donate_argnums=(0, 1, 2))
-_carry_assemble = jax.jit(carry_assemble)
-
-
-class _CarryHandle:
-    """Refcounted reference to one slab row of a :class:`DeviceCarryPool`.
-
-    Stored in :class:`CarryStore` in place of a raw carry tuple: each
-    store that holds the handle ``retain``\\ s it, and the row is
-    returned to the pool's free list when the last reference is
-    ``release``\\ d (eviction, overwrite, or ``clear``). ``materialize``
-    yields the ``(S*, f*, S̄)`` view lazily — device slices, no host
-    sync."""
-
-    __slots__ = ("pool", "shape", "row", "refs")
-
-    def __init__(self, pool: "DeviceCarryPool", shape: Tuple[int, int],
-                 row: int):
-        self.pool = pool
-        self.shape = shape
-        self.row = row
-        self.refs = 0
-
-    def retain(self) -> None:
-        """Count one more store holding this row."""
-        self.refs += 1
-
-    def release(self) -> None:
-        """Drop one reference; frees the slab row at zero."""
-        self.refs -= 1
-        if self.refs <= 0 and self.row >= 0:
-            self.pool._free(self.shape, self.row)
-            self.row = -1
-
-    def materialize(self) -> tuple:
-        """The stored ``(S*, f*, S̄)`` as lazy device slices."""
-        return self.pool._read(self.shape, self.row)
-
-    def __iter__(self):
-        """Duck-type as the carry tuple itself: iterating a handle
-        yields the materialized ``(S*, f*, S̄)`` device parts."""
-        return iter(self.materialize())
-
-    def __len__(self) -> int:
-        return 3
-
-
-class _LazyCarry:
-    """Tuple-shaped view of a pooled carry handed out in results.
-
-    Slicing three device arrays out of the pool costs real dispatch
-    time, and most callers never look at ``result.carry`` — so Tier-0
-    hits and single-device Tier-2 results hand out this view instead.
-    It retains the handle (pinning the slab row even if the store evicts
-    the entry later) and slices the parts out only on first access; the
-    reference drops when the view is garbage-collected."""
-
-    __slots__ = ("_handle", "_parts")
-
-    def __init__(self, handle: "_CarryHandle"):
-        handle.retain()
-        self._handle = handle
-        self._parts = None
-
-    def materialize(self) -> tuple:
-        if self._parts is None:
-            # once sliced, the parts reference the slab *value* at this
-            # moment (jax arrays are immutable), so the row pin can drop
-            self._parts = self._handle.materialize()
-            self._handle.release()
-            self._handle = None
-        return self._parts
-
-    def __iter__(self):
-        return iter(self.materialize())
-
-    def __len__(self) -> int:
-        return 3
-
-    def __getitem__(self, i):
-        return self.materialize()[i]
-
-    def __del__(self):
-        h = self._handle
-        if h is not None:
-            try:
-                h.release()
-            except Exception:  # pragma: no cover - interpreter teardown
-                pass
-
-
-class DeviceCarryPool:
-    """Device-resident slab storage for warm-start carries.
-
-    One growable slab triple per padded shape — ``S``: (cap, n, m),
-    ``f``: (cap,), ``C``: (cap, n, m), all float32, all device-resident —
-    handing out refcounted :class:`_CarryHandle` rows. Carries move
-    between the slabs and a tier launch through one compiled program per
-    launch in each direction:
-
-      * ``write_back`` stores chosen output slots of a launch as fresh
-        rows with ONE donated in-place program (``carry_write_back``);
-        ``put`` is its one-row case, for carries that arrive alone,
-      * ``assemble`` builds a launch's stacked carry input with ONE
-        program (``carry_assemble``): each slot takes a row, or the cold
-        prior computed from the launch's mask,
-      * rows are recycled through a free list as store evictions release
-        their handles.
-
-    Slabs grow geometrically (``jnp.concatenate`` with a zero block), so
-    amortized write cost stays O(row). The pool never syncs to host; the
-    persistence layer materializes handles lazily at snapshot-save time.
-    """
-
-    def __init__(self, block: int = 32):
-        self.block = max(int(block), 1)
-        self._slabs: Dict[Tuple[int, int], dict] = {}
-        self.puts = 0                # rows written
-        self.writes = 0              # write programs run
-        self.gathers = 0             # assembly programs run
-        # steady-state drains assemble the same row sets every time;
-        # caching the device index array saves a host→device transfer
-        # dispatch per launch
-        self._idx_cache: "OrderedDict[tuple, jax.Array]" = OrderedDict()
-
-    def _slab(self, shape: Tuple[int, int]) -> dict:
-        slab = self._slabs.get(shape)
-        if slab is None:
-            n, m = shape
-            cap = self.block
-            slab = {"S": jnp.zeros((cap, n, m), jnp.float32),
-                    "f": jnp.zeros((cap,), jnp.float32),
-                    "C": jnp.zeros((cap, n, m), jnp.float32),
-                    "free": list(range(cap - 1, -1, -1)), "cap": cap}
-            self._slabs[shape] = slab
-        return slab
-
-    def _alloc(self, shape: Tuple[int, int]) -> int:
-        """Take a free row, growing the slab when none is left."""
-        slab = self._slab(shape)
-        if not slab["free"]:
-            old = slab["cap"]
-            grow = max(old, self.block)
-            n, m = shape
-            slab["S"] = jnp.concatenate(
-                [slab["S"], jnp.zeros((grow, n, m), jnp.float32)])
-            slab["f"] = jnp.concatenate(
-                [slab["f"], jnp.zeros((grow,), jnp.float32)])
-            slab["C"] = jnp.concatenate(
-                [slab["C"], jnp.zeros((grow, n, m), jnp.float32)])
-            slab["cap"] = old + grow
-            slab["free"] = list(range(old + grow - 1, old - 1, -1))
-        return slab["free"].pop()
-
-    def write_back(self, S, f, C, slots: Sequence[int],
-                   seeds: Sequence[int] = ()) -> List[_CarryHandle]:
-        """Store output slots of one launch — ``S``: (L, n, m), ``f``:
-        (L,), ``C``: (L, n, m), device or host — as fresh rows with one
-        program, and return their (unretained) handles: ``slots`` with
-        their own f*, then ``seeds`` with f* = -inf. The whole outputs go
-        in; the program picks the slots."""
-        shape = (int(S.shape[1]), int(S.shape[2]))
-        pos = list(slots) + list(seeds)
-        rows = [self._alloc(shape) for _ in pos]
-        slab = self._slabs[shape]
-        idx = np.zeros((2, S.shape[0]), np.int32)
-        idx[0] = slab["cap"]
-        idx[0, pos] = rows
-        idx[1, list(seeds)] = 1
-        slab["S"], slab["f"], slab["C"] = _carry_write_back(
-            slab["S"], slab["f"], slab["C"], S, f, C, idx)
-        self.puts += len(rows)
-        self.writes += 1
-        return [_CarryHandle(self, shape, r) for r in rows]
-
-    def put(self, carry: tuple) -> _CarryHandle:
-        """Write one ``(S*, f*, S̄)`` carry into a slab row and return its
-        (unretained) handle. Accepts device or host arrays; parts are
-        cast to the slab's float32."""
-        S, f, C = (p[None] if isinstance(p, jax.Array)
-                   else np.asarray(p, np.float32)[None] for p in carry)
-        return self.write_back(S, f, C, [0])[0]
-
-    def assemble(self, carries: Sequence[Optional[_CarryHandle]],
-                 maskb) -> tuple:
-        """Stacked ``(S, f, C)`` launch inputs for one batch with mask
-        stack ``maskb`` (B, n, m): slot b takes the row of
-        ``carries[b]``, or the cold prior of ``maskb[b]`` where it is
-        None — one program, all on device. The result is freshly
-        allocated, so callers may donate it to a launch."""
-        slab = self._slab((int(maskb.shape[1]), int(maskb.shape[2])))
-        rows = tuple(-1 if c is None else c.row for c in carries)
-        idx = self._idx_cache.get(rows)
-        if idx is None:
-            # from a numpy array: a plain upload, no conversion program
-            idx = jnp.asarray(np.asarray(rows, np.int32))
-            self._idx_cache[rows] = idx
-            while len(self._idx_cache) > 256:
-                self._idx_cache.popitem(last=False)
-        self.gathers += 1
-        return _carry_assemble(slab["S"], slab["f"], slab["C"], idx, maskb)
-
-    def _read(self, shape: Tuple[int, int], row: int) -> tuple:
-        slab = self._slabs[shape]
-        return (slab["S"][row], slab["f"][row], slab["C"][row])
-
-    def _free(self, shape: Tuple[int, int], row: int) -> None:
-        slab = self._slabs.get(shape)
-        if slab is not None:
-            slab["free"].append(row)
-
-    @property
-    def live_rows(self) -> int:
-        """Rows currently referenced by at least one store entry."""
-        return sum(s["cap"] - len(s["free"])
-                   for s in self._slabs.values())
-
-
 class MatcherService:
     """Warm-start online wrapper around Algorithm 1.
 
     Single-device by default; pass ``mesh`` + ``axis_names`` to run each
     bucket's executable as the collective-fused distributed matcher.
-    ``tiered=False`` disables the staged pipeline and restores the
-    uniform one-swarm-launch-per-batch drain (the PR-2 baseline);
+    ``tiered=False`` turns Tier 0/1 off, so the tier walk sends every
+    request to a uniform swarm launch per batch (the PR-2 baseline);
     ``similarity=False`` keeps the pipeline but disables Tier-1 rebases
     (the content-keyed baseline).
 
@@ -944,9 +434,9 @@ class MatcherService:
         assert self.batch_classes and self.batch_classes[0] >= 1
         self.tiered = tiered
         self.similarity = similarity
-        # pipelined=False restores the legacy serial drain (host-staged
-        # carry stacking, dispatch → blocking fetch per launch) — the
-        # baseline arm bench_pipeline measures the pipeline against
+        # pipelined=False: the tier walk resolves each launch as it is
+        # dispatched and stages carries through host numpy — the serial
+        # arm bench_pipeline measures the pipeline against
         self.pipelined = bool(pipelined)
         if donate_buffers is None:
             donate_buffers = pallas_compat.donation_supported()
@@ -954,10 +444,10 @@ class MatcherService:
         self.stats = ServiceStats()
         self._carries = CarryStore(warm_capacity, sim_capacity, self.stats,
                                    sim_index=sim_index)
-        self._pool = DeviceCarryPool()
-        # per-bucket pre-finished pad carry, pooled once and pinned so
-        # padded warm batches stay all-handle (one-gather launch inputs)
-        self._pad_handles: Dict[Tuple[int, int], _CarryHandle] = {}
+        self._path = CarryPath(self.stats, single_device=mesh is None,
+                               pipelined=self.pipelined)
+        self._pool = self._path.pool
+        self._pad_handles = self._path.pads
         self._compiled: "OrderedDict[Tuple, object]" = OrderedDict()
         self._pending: List[_PendingRequest] = []
         # -- persistence wiring -------------------------------------------
@@ -1153,25 +643,24 @@ class MatcherService:
         return (req.workload_key, req.Qp.shape[0], req.Gp.shape[0],
                 req.cdigest)
 
+    # a stored carry as its (S*, f*, S̄) tuple, whatever its stored form
+    _carry_tuple = staticmethod(carry_tuple)
+
     def _get_carry(self, warm_key):
         if not self.warm_start:
             self.stats.warm_misses += 1
             return None, False
         return self._carries.get(warm_key)
 
-    def _put_carry(self, warm_key, carry):
-        if self.warm_start:
-            self._carries.put(warm_key, carry)
-
     def _store_carry(self, req: _PendingRequest, warm_key, stored,
                      similar: bool) -> None:
-        """Store a fresh carry (a pool handle, or the host carry on a
-        mesh service, whose launch outputs carry mesh shardings the
-        single-device slabs can't hold) under the exact key, and — when
-        the call produced a served decision (``similar``) on a known
-        platform state — under the similarity key too, so future drifted
-        states can rebase it."""
-        self._put_carry(warm_key, stored)
+        """Store a fresh carry (in its ``CarryPath`` stored form) under
+        the exact key, and — when the call produced a served decision
+        (``similar``) on a known platform state — under the similarity
+        key too, so future drifted states can rebase it."""
+        if not self.warm_start:
+            return
+        self._carries.put(warm_key, stored)
         if similar and self.similarity and req.engine_sig is not None:
             self._carries.put_similar(req.qdigest, req.bucket,
                                       req.engine_sig, stored)
@@ -1197,36 +686,29 @@ class MatcherService:
         if self._ckpt is None:
             raise RuntimeError("save_snapshot needs persist_dir "
                                "(or REPRO_PERSIST_DIR)")
-        exact_items, sim_items = self._carries.export_state()
         arrays: Dict[str, np.ndarray] = {}
-        exact_keys, exact_carries = [], []
-        for k, c in exact_items:
-            try:
-                exact_keys.append(persist.encode_key(k))
-            except TypeError:
-                self.stats.snapshot_skipped_keys += 1
-                continue
-            # device-pool handles materialize to lazy device slices here;
-            # the ONE blocking transfer happens inside carry_leaves
-            exact_carries.append(self._carry_tuple(c))
-        sim_keys, sim_carries = [], []
-        for k, c in sim_items:
-            try:
-                sim_keys.append(persist.encode_key(k))
-            except TypeError:
-                self.stats.snapshot_skipped_keys += 1
-                continue
-            sim_carries.append(self._carry_tuple(c))
-        arrays.update(persist.carry_leaves("exact", exact_carries))
-        arrays.update(persist.carry_leaves("sim", sim_carries))
+        keys: Dict[str, List] = {}
+        for store, items in zip(("exact", "sim"),
+                                self._carries.export_state()):
+            keys[store], carries = [], []
+            for k, c in items:
+                try:
+                    keys[store].append(persist.encode_key(k))
+                except TypeError:
+                    self.stats.snapshot_skipped_keys += 1
+                    continue
+                # device-pool handles materialize to lazy device slices
+                # here; the ONE blocking transfer is inside carry_leaves
+                carries.append(carry_tuple(c))
+            arrays.update(persist.carry_leaves(store, carries))
         # flat-dict checkpoints must be non-empty for restore_flat to see
         # a committed structure even when no carries are stored yet
         arrays["snapshot.marker"] = np.zeros((), np.int8)
         extras = {
             "format_version": persist.SNAPSHOT_VERSION,
             "config_digest": self.config_digest,
-            "exact_keys": exact_keys,
-            "sim_keys": sim_keys,
+            "exact_keys": keys["exact"],
+            "sim_keys": keys["sim"],
             "calibration": {
                 "prune_problems": int(self.stats.prune_problems),
                 "prune_sweeps": int(self.stats.prune_sweeps),
@@ -1270,21 +752,17 @@ class MatcherService:
                 extras.get("config_digest") != self.config_digest:
             self.stats.snapshot_stale_skipped += 1
             return None
-        exact_keys = [persist.decode_key(k) for k in extras["exact_keys"]]
-        sim_keys = [persist.decode_key(k) for k in extras["sim_keys"]]
-        exact_carries = persist.carries_from_leaves(
-            "exact", arrays, len(exact_keys))
-        sim_carries = persist.carries_from_leaves(
-            "sim", arrays, len(sim_keys))
-        if self.mesh is None:
-            # restored carries go straight back to device residency: one
-            # pool row per entry, uploaded once; rows free themselves as
-            # store replay/evictions release the handles
-            exact_carries = [self._pool.put(c) for c in exact_carries]
-            sim_carries = [self._pool.put(c) for c in sim_carries]
+        loaded = []
+        for store in ("exact", "sim"):
+            keys = [persist.decode_key(k) for k in extras[f"{store}_keys"]]
+            loaded.append(list(zip(keys, persist.carries_from_leaves(
+                store, arrays, len(keys)))))
+        # restored carries go back to the service's stored form (pool rows
+        # on a single device, uploaded once); rows free themselves as
+        # store replay/evictions release the handles
         n_exact, n_sim = self._carries.import_state(
-            list(zip(exact_keys, exact_carries)),
-            list(zip(sim_keys, sim_carries)))
+            *[[(k, self._path.keep(c)) for k, c in items]
+              for items in loaded])
         calib = extras.get("calibration", {})
         self.stats.prune_problems += int(calib.get("prune_problems", 0))
         self.stats.prune_sweeps += int(calib.get("prune_sweeps", 0))
@@ -1303,7 +781,7 @@ class MatcherService:
         ``config_digest``, so the restore is accepted) but no AOT cache
         (snapshots only; nothing is compiled). Compared: both carry
         stores' key sequences in LRU order, every carry leaf
-        (``dtype``/``shape``/bytes — via :meth:`_carry_tuple`, so
+        (``dtype``/``shape``/bytes — via ``carries.carry_tuple``, so
         device-pool handles materialize identically on both sides) and
         the prune-sweep calibration counters. Raises ``AssertionError``
         naming the first divergence; returns True when the round trip
@@ -1328,8 +806,8 @@ class MatcherService:
 
         def _leaves(svc):
             exact, sim = svc._carries.export_state()
-            return ([(k, svc._carry_tuple(c)) for k, c in exact],
-                    [(k, svc._carry_tuple(c)) for k, c in sim])
+            return ([(k, carry_tuple(c)) for k, c in exact],
+                    [(k, carry_tuple(c)) for k, c in sim])
 
         for store, mine, theirs in zip(("exact", "sim"), _leaves(self),
                                        _leaves(twin)):
@@ -1445,47 +923,6 @@ class MatcherService:
                     "prune_sweeps")
         return {k: rec.outs[k] for k in keys}
 
-    @staticmethod
-    def _carry_tuple(carry) -> tuple:
-        """A stored carry as its ``(S*, f*, S̄)`` tuple: device-pool
-        handles and lazy result views are materialized (lazy device
-        slices, no host sync); plain tuples pass through."""
-        if isinstance(carry, (_CarryHandle, _LazyCarry)):
-            return carry.materialize()
-        return carry
-
-    @property
-    def _pooled(self) -> bool:
-        """Whether launches take their carries straight from the device
-        pool (``DeviceCarryPool.assemble``): the single-device pipelined
-        drain. Mesh services and the ``pipelined=False`` arm stage them
-        through host numpy (``_stack_carries``)."""
-        return self.mesh is None and self.pipelined
-
-    def _stack_carries(self, carries: List) -> tuple:
-        """Stacked ``(B, ...)`` carry inputs for one launch of a mesh
-        service or the ``pipelined=False`` arm: the legacy host staging
-        the pooled path replaced. Each carry part is pulled to host with
-        a blocking ``np.asarray`` and re-stacked with numpy. Those
-        implicit device→host transfers are what the pooled path
-        eliminates, so they are charged to the host-sync census here
-        (one sync per device-resident part)."""
-        mats = [self._carry_tuple(c) for c in carries]
-        stacked = []
-        for i in range(3):
-            parts = []
-            for mat in mats:
-                p = mat[i]
-                if isinstance(p, jax.Array):
-                    t0 = time.perf_counter()
-                    p = np.asarray(p)
-                    self.stats.host_syncs += 1
-                    self.stats.host_sync_wall_s += time.perf_counter() - t0
-                    self.stats.host_bytes_transferred += int(p.nbytes)
-                parts.append(np.asarray(p))
-            stacked.append(np.stack(parts))
-        return tuple(stacked)
-
     def _donate_argnums(self, kind: str) -> Tuple[int, ...]:
         """Argnums a fresh jit build of ``kind`` may donate (empty when
         ``donate_buffers`` is off or the kind's inputs can alias stored
@@ -1534,9 +971,9 @@ class MatcherService:
                                  carry=None, warm_hit=False, t0=t0)
             nb = self._lookup_neighbor(item)
             if nb is not None:
-                residual = self._launch_revalidate(bucket, [item], [nb],
-                                                   tier=1)
-                if not residual:
+                self._apply_all([self._dispatch_revalidate(
+                    bucket, [item], [nb], tier=1, miss_sink=[])])
+                if item.result is not None:
                     res = item.result
                     res.latency_s = time.perf_counter() - t0
                     return res
@@ -1547,10 +984,10 @@ class MatcherService:
         compile_hit = self.stats.compile_cache_hits > hits_before
 
         if carry0 is None:
-            carry0 = self._carry_tuple(seed) if seed is not None \
+            carry0 = carry_tuple(seed) if seed is not None \
                 else pso.default_carry(jnp.asarray(maskp))
         else:
-            carry0 = self._carry_tuple(carry0)
+            carry0 = carry_tuple(carry0)
 
         if self.mesh is None:
             outs = fn(key, Qp, Gp, maskp, carry0)
@@ -1559,8 +996,7 @@ class MatcherService:
                                       for a in self.axis_names]))
             keys = jax.random.split(key, num_shards)
             outs = fn(keys, Qp, Gp, maskp, carry0)
-        if isinstance(seed, _CarryHandle):
-            seed.release()
+        release(seed)
 
         # the controller state stays device-resident for the store; the
         # result itself resolves through ONE counted blocking fetch
@@ -1569,9 +1005,8 @@ class MatcherService:
         res = ServiceMatchResult(**{f.name: getattr(base, f.name)
                                     for f in dataclasses.fields(MatchResult)})
         if self.warm_start:
-            stored = self._pool.put((outs["S_star"], outs["f_star"],
-                                     outs["S_bar"])) \
-                if self.mesh is None else res.carry
+            stored = self._path.keep(
+                (outs["S_star"], outs["f_star"], outs["S_bar"]), res.carry)
             self._store_carry(req, warm_key, stored, similar=res.found)
         self.stats.epochs_run += res.epochs_run
         self._note_prune(1, res.prune_sweeps)
@@ -1621,45 +1056,40 @@ class MatcherService:
     def drain(self) -> List[ServiceMatchResult]:
         """Flush the pending queue through the tiered pipeline.
 
-        Same-bucket requests form one pipeline group: Tier 0 revalidates
-        every stored carry in one cheap launch, Tier 1 rebases similar
-        carries for the misses, and only the residual requests launch the
-        Tier-2 swarm (chunked to batch classes). Results come back in
-        submission order; each request's ``latency_s`` is the wall time
-        of the launches that actually served it, so an easy request no
-        longer pays a hard neighbour's epochs.
+        Same-bucket requests form one group, and one tier walk
+        (``_walk``) serves the groups: Tier 0 revalidates every stored
+        carry in one cheap launch, Tier 1 rebases similar carries for the
+        misses, and only the residual requests launch the Tier-2 swarm
+        (chunked to batch classes). Results come back in submission
+        order; each request's ``latency_s`` is the wall time of the
+        launches that actually served it, so an easy request no longer
+        pays a hard neighbour's epochs.
 
-        With ``pipelined=True`` (the default) each tier dispatches its
-        launches for EVERY bucket group before anything blocks: the host
-        builds and enqueues group B's batch while the device still runs
-        group A's, and each stage resolves through one batched fetch —
-        an all-warm drain costs exactly one blocking host sync
-        (``stats.host_syncs_per_drain``). ``pipelined=False`` restores
-        the legacy serial walk: carries staged through host numpy (one
-        implicit sync per device-resident carry part) and one blocking
-        fetch per launch.
+        With ``pipelined=True`` (the default) one walk covers EVERY
+        bucket group: each tier dispatches all its launches before
+        anything blocks, so the host builds and enqueues group B's batch
+        while the device still runs group A's, and each stage resolves
+        through one batched fetch — an all-warm drain costs exactly one
+        blocking host sync (``stats.host_syncs_per_drain``).
+        ``pipelined=False`` walks one group at a time, fetches each
+        launch as soon as it is dispatched, and stages carries through
+        host numpy (one implicit sync per device-resident carry part):
+        the serial arm the pooled path is checked against bitwise.
         """
         pending, self._pending = self._pending, []
         if not pending:
             return []
         self.stats.drains += 1
-        results: List[Optional[ServiceMatchResult]] = [None] * len(pending)
         groups: "OrderedDict[Tuple[int, int], List[int]]" = OrderedDict()
         for i, req in enumerate(pending):
             groups.setdefault(req.bucket, []).append(i)
-        if self._tiers_active() and self.pipelined:
-            self._drain_pipelined(pending, groups, results)
-            return results  # type: ignore[return-value]
-        max_chunk = self.batch_classes[-1]
-        for bucket, idxs in groups.items():
-            reqs = [pending[i] for i in idxs]
-            if self._tiers_active():
-                self._run_pipeline(bucket, reqs, idxs, results)
-            else:
-                for pos in range(0, len(idxs), max_chunk):
-                    chunk = idxs[pos:pos + max_chunk]
-                    self._launch_batch_legacy(
-                        bucket, [pending[i] for i in chunk], chunk, results)
+        walks = ([list(groups.items())] if self.pipelined
+                 else [[group] for group in groups.items()])
+        results: List[Optional[ServiceMatchResult]] = [None] * len(pending)
+        for walk in walks:
+            for it in self._walk(pending, walk):
+                it.result.latency_s = it.latency_s
+                results[it.ticket] = it.result
         return results  # type: ignore[return-value]
 
     def match_many(self, problems: Sequence[Tuple[Graph, Graph]],
@@ -1682,7 +1112,7 @@ class MatcherService:
 
     def _intake(self, reqs: List[_PendingRequest], tickets: List[int]
                 ) -> List[_PipelineItem]:
-        """Shared per-request intake for both drain paths: call/budget
+        """Per-request intake of one bucket group: call/budget
         accounting, exact-carry lookup, group coalescing stats."""
         t_start = time.perf_counter()
         items: List[_PipelineItem] = []
@@ -1700,104 +1130,66 @@ class MatcherService:
             self.stats.coalesced_requests += len(items)
         return items
 
-    def _run_pipeline(self, bucket, reqs: List[_PendingRequest],
-                      tickets: List[int], results: List) -> None:
-        """Revalidate → similarity-rebase → swarm for one bucket group."""
-        items = self._intake(reqs, tickets)
-        max_chunk = self.batch_classes[-1]
+    def _walk(self, pending: List[_PendingRequest],
+              groups: List[Tuple[Tuple[int, int], List[int]]]
+              ) -> List[_PipelineItem]:
+        """Revalidate → similarity-rebase → swarm over bucket ``groups``
+        ((bucket, tickets) pairs); returns every item, served.
 
-        # ---- Tier 0: batched revalidation of every stored carry ----
-        residual: List[_PipelineItem] = [it for it in items
-                                         if it.carry is None]
-        cand = [it for it in items if it.carry is not None]
-        for pos in range(0, len(cand), max_chunk):
-            chunk = cand[pos:pos + max_chunk]
-            residual.extend(self._launch_revalidate(
-                bucket, chunk, [it.carry for it in chunk], tier=0))
+        Each stage's launches are dispatched lazily from a generator and
+        resolved by ``_resolve``. Tier 0/1 run only when
+        ``_tiers_active()``: otherwise every item goes to the swarm with
+        its exact carry or the cold prior. Store keys embed the bucket,
+        so groups never interact, and within a group the tier order and
+        miss order are the same whether the stages cover one group or
+        all of them."""
+        size = self.batch_classes[-1]
+        tiers = self._tiers_active()
+        state = []                   # (bucket, items, residual) per group
 
-        # ---- Tier 1: rebase the nearest similar carry for the misses ----
-        if self.similarity and residual:
-            t1_items, t1_carries = [], []
-            for it in residual:
-                nb = self._lookup_neighbor(it)
-                if nb is not None:
-                    t1_items.append(it)
-                    t1_carries.append(nb)
-            for pos in range(0, len(t1_items), max_chunk):
-                self._launch_revalidate(
-                    bucket, t1_items[pos:pos + max_chunk],
-                    t1_carries[pos:pos + max_chunk], tier=1)
+        def revalidate():            # Tier 0: every stored exact carry
+            for bucket, idxs in groups:
+                items = self._intake([pending[i] for i in idxs], idxs)
+                residual = [it for it in items if it.carry is None]
+                state.append((bucket, items, residual))
+                if not tiers:
+                    continue
+                cand = [it for it in items if it.carry is not None]
+                for chunk in _chunks(cand, size):
+                    yield self._dispatch_revalidate(
+                        bucket, chunk, [it.carry for it in chunk], tier=0,
+                        miss_sink=residual)
 
-        # ---- Tier 2: swarm sized to the residual (hard) subset ----
-        residual = [it for it in items if it.result is None]
-        for pos in range(0, len(residual), max_chunk):
-            self._launch_swarm(bucket, residual[pos:pos + max_chunk])
+        def rebase():                # Tier 1: the misses' nearest carries
+            for bucket, _, residual in state:
+                pairs = [(it, nb) for it in residual
+                         if (nb := self._lookup_neighbor(it)) is not None]
+                for chunk in _chunks(pairs, size):
+                    yield self._dispatch_revalidate(
+                        bucket, [it for it, _ in chunk],
+                        [nb for _, nb in chunk], tier=1, miss_sink=[])
 
-        for it in items:
-            it.result.latency_s = it.latency_s
-            results[it.ticket] = it.result
+        def swarm():                 # Tier 2: the residual
+            for bucket, items, _ in state:
+                residual = [it for it in items if it.result is None]
+                for chunk in _chunks(residual, size):
+                    yield self._dispatch_swarm(bucket, chunk)
 
-    def _drain_pipelined(self, pending: List[_PendingRequest],
-                         groups: "OrderedDict[Tuple[int, int], List[int]]",
-                         results: List) -> None:
-        """Async-dispatch drain: every bucket group's launches for one
-        tier go out before ANY of them blocks, then the whole stage
-        resolves through a single batched fetch (``_apply_all``).
+        self._resolve(revalidate())
+        if tiers and self.similarity:
+            self._resolve(rebase())
+        self._resolve(swarm())
+        return [it for _, items, _ in state for it in items]
 
-        Host-side tier decisions for later groups (padding, carry
-        assembly, store probes) overlap device execution of earlier
-        groups' launches, and the per-stage sync count is 1 instead of
-        one per launch. Results and stored carries are bitwise identical
-        to the serial walk: store keys embed the bucket, so groups never
-        interact, and within a group the tier order and miss order are
-        preserved exactly."""
-        max_chunk = self.batch_classes[-1]
-        # ---- Tier 0: dispatch every group's revalidation launches ----
-        recs: List[_LaunchRecord] = []
-        state = []                 # (bucket, items, residual) per group
-        for bucket, idxs in groups.items():
-            items = self._intake([pending[i] for i in idxs], idxs)
-            residual = [it for it in items if it.carry is None]
-            cand = [it for it in items if it.carry is not None]
-            for pos in range(0, len(cand), max_chunk):
-                chunk = cand[pos:pos + max_chunk]
-                recs.append(self._dispatch_revalidate(
-                    bucket, chunk, [it.carry for it in chunk], tier=0,
-                    miss_sink=residual))
-            state.append((bucket, items, residual))
-        self._apply_all(recs)
-
-        # ---- Tier 1: rebase lookups + dispatches across all groups ----
-        recs = []
-        for bucket, items, residual in state:
-            if not (self.similarity and residual):
-                continue
-            t1_items, t1_carries = [], []
-            for it in residual:
-                nb = self._lookup_neighbor(it)
-                if nb is not None:
-                    t1_items.append(it)
-                    t1_carries.append(nb)
-            for pos in range(0, len(t1_items), max_chunk):
-                recs.append(self._dispatch_revalidate(
-                    bucket, t1_items[pos:pos + max_chunk],
-                    t1_carries[pos:pos + max_chunk], tier=1,
-                    miss_sink=[]))
-        self._apply_all(recs)
-
-        # ---- Tier 2: swarm the residual of every group ----
-        recs = []
-        for bucket, items, _ in state:
-            residual = [it for it in items if it.result is None]
-            for pos in range(0, len(residual), max_chunk):
-                recs.append(self._dispatch_swarm(
-                    bucket, residual[pos:pos + max_chunk]))
-        self._apply_all(recs)
-
-        for _, items, _ in state:
-            for it in items:
-                it.result.latency_s = it.latency_s
-                results[it.ticket] = it.result
+    def _resolve(self, launches) -> None:
+        """Resolve one stage. Pipelined, every launch is dispatched
+        before ONE fetch covers them all; serial, each launch is fetched
+        and applied as soon as it is dispatched."""
+        if self.pipelined:
+            self._apply_all(list(launches))
+        else:
+            for rec in launches:
+                self._apply_all([rec])
 
     def _apply_all(self, recs: List[_LaunchRecord]) -> None:
         """Resolve one pipeline stage: ONE blocking fetch covering every
@@ -1830,29 +1222,12 @@ class MatcherService:
         self.stats.sim_neighbor_hits += 1
         return nb[1]
 
-    def _launch_revalidate(self, bucket, items: List[_PipelineItem],
-                           carries: List[tuple], tier: int
-                           ) -> List[_PipelineItem]:
-        """One *serial* Tier-0/1 launch: dispatch, then a blocking fetch
-        of just this launch's outputs (one sync per launch — the arm
-        ``bench_pipeline`` measures the pipelined drain against).
-
-        Hits get their result attached (0 epochs, revalidation cost);
-        misses are returned for the next tier. Tier-1 misses keep the
-        rebased carry (f* reset to -inf) as their Tier-2 swarm seed."""
-        misses: List[_PipelineItem] = []
-        rec = self._dispatch_revalidate(bucket, items, carries, tier,
-                                        miss_sink=misses)
-        self._apply_revalidate(rec, self._sync_fetch(self._fetch_tree(rec),
-                                                     rec.drain))
-        return misses
-
     def _dispatch_revalidate(self, bucket, items: List[_PipelineItem],
                              carries: List[tuple], tier: int,
                              miss_sink: List) -> _LaunchRecord:
         """Enqueue one Tier-0/1 revalidation launch (no host sync): pad
-        the batch, stack the carries device-side, dispatch. The returned
-        record resolves via ``_apply_revalidate`` once its outputs are
+        the batch, build its inputs, dispatch. The returned record
+        resolves via ``_apply_revalidate`` once its outputs are
         fetched."""
         B = len(items)
         bclass = self._batch_class(B)
@@ -1864,32 +1239,15 @@ class MatcherService:
             fn = self._executable_reval(bucket, bclass)
             compile_hit = self.stats.compile_cache_hits > hits_before
 
-            reqs = [it.req for it in items]
-            stored = list(carries)
-            padded, carries = list(reqs), list(carries)
-            if bclass > B:
-                pad_req, pad_carry = self._pad_slot(bucket, reqs[0],
-                                                    carries[0])
-                padded += [pad_req] * (bclass - B)
-                carries += [pad_carry] * (bclass - B)
-            Qb = np.stack([r.Qp for r in padded])
-            Gb = np.stack([r.Gp for r in padded])
-            maskb = np.stack([r.maskp for r in padded])
-            if self._pooled:
-                maskb = jnp.asarray(maskb)
-                carry0 = self._pool.assemble(carries, maskb)
-            else:
-                carry0 = self._stack_carries(carries)
-            if self.mesh is None and self._donate_argnums("reval"):
-                self.stats.donated_launches += 1
-
+            _, Qb, Gb, maskb, carry0 = self._launch_inputs(
+                "reval", bucket, items, carries, bclass)
             outs = fn(Qb, Gb, maskb, carry0)
             tstats.launches += 1
             tstats.checked += B
             return _LaunchRecord(kind="reval", bucket=bucket, items=items,
                                  tier=tier, B=B, bclass=bclass,
                                  compile_hit=compile_hit, outs=outs,
-                                 carries=stored, miss_sink=miss_sink,
+                                 carries=carries, miss_sink=miss_sink,
                                  drain=drain)
 
     def _apply_revalidate(self, rec: _LaunchRecord, host: dict) -> None:
@@ -1916,29 +1274,21 @@ class MatcherService:
             # Tier 1 stores every hit's rebased carry, and keeps each
             # cold miss's rebased carry (f* reset to -inf) as its Tier-2
             # seed; a miss with a failed exact carry swarms from that
-            # instead. Single-device services write them all to pool rows
-            # in one program; a seed row is held by its item until its
-            # swarm launch is dispatched
-            written = {}
+            # instead. A seed is held by its item until its swarm launch
+            # is dispatched
+            kept = {}
             if tier == 1:
-                hit = [j for j in range(B) if ok[j]]
-                seed = [j for j, it in enumerate(items)
-                        if not ok[j] and it.carry is None]
-                if self.mesh is None and (hit or seed):
-                    written = dict(zip(hit + seed, self._pool.write_back(
-                        rec.outs["S_star"], rec.outs["fitness"],
-                        rec.outs["S_bar"], hit, seed)))
+                kept = self._path.keep_rebased(
+                    rec.outs, host, [j for j in range(B) if ok[j]],
+                    [j for j, it in enumerate(items)
+                     if not ok[j] and it.carry is None])
 
             for j, it in enumerate(items):
                 it.latency_s = done - it.t0
                 if not ok[j]:
-                    if tier == 1 and it.carry is None:
-                        if j in written:
-                            it.seed = written[j]
-                            it.seed.retain()
-                        else:
-                            it.seed = (S_rb[j], np.float32(-np.inf),
-                                       S_bar_rb[j])
+                    if j in kept:
+                        it.seed = kept[j]
+                        retain(it.seed)
                     rec.miss_sink.append(it)
                     continue
                 tstats.hits += 1
@@ -1949,15 +1299,13 @@ class MatcherService:
                     # untouched; its f* comes from the output echo, not a
                     # per-item device read, and the result's carry is a lazy
                     # view — no pool slicing unless the caller looks at it
-                    carry = (_LazyCarry(carries[j])
-                             if isinstance(carries[j], _CarryHandle)
-                             else self._carry_tuple(carries[j]))
+                    carry = result_view(carries[j])
                     f_res = float(f_carry[j])
                 else:
                     carry = (S_rb[j], fits[j], S_bar_rb[j])
                     f_res = float(fits[j])
-                    self._store_carry(it.req, it.warm_key,
-                                      written.get(j, carry), similar=True)
+                    self._store_carry(it.req, it.warm_key, kept[j],
+                                      similar=True)
                 it.result = self._revalidated_result(
                     it, maps[j], f_res, carry, tier=tier, batch=B,
                     compile_hit=rec.compile_hit, prune_sweeps=int(sweeps[j]))
@@ -2008,31 +1356,31 @@ class MatcherService:
         maskp = np.zeros((n_pad, m_pad), dtype=like.maskp.dtype)
         idx = np.arange(n_pad)
         maskp[idx, idx] = 1
-        S_id = np.zeros((n_pad, m_pad), np.float32)
-        S_id[idx, idx] = 1.0
-        # f* = +inf clears ANY early_exit_fitness bound, so the pad slot
-        # is pre-finished regardless of the configured threshold
-        if self.mesh is None:
-            carry = self._pad_handles.get(bucket)
-            if carry is None:
-                carry = self._pool.put((S_id, np.float32(np.inf), S_id))
-                carry.retain()     # pinned: pads recur on every drain
-                self._pad_handles[bucket] = carry
-        else:
-            carry = (S_id, np.float32(np.inf), S_id.copy())
         req = _PendingRequest(key=like.key, workload_key=None,
                               order=np.arange(n_pad),
                               crop=(n_pad, m_pad), bucket=bucket,
                               Qp=Qp, Gp=Gp, maskp=maskp)
-        return req, carry
+        return req, self._path.pad(bucket)
 
-    def _launch_swarm(self, bucket, items: List[_PipelineItem]) -> None:
-        """One *serial* Tier-2 swarm launch over the pipeline's residual
-        items: dispatch, then a blocking fetch of just this launch's
-        outputs (the one-sync-per-launch baseline arm)."""
-        rec = self._dispatch_swarm(bucket, items)
-        self._apply_swarm(rec, self._sync_fetch(self._fetch_tree(rec),
-                                                rec.drain))
+    def _launch_inputs(self, kind: str, bucket, items: List[_PipelineItem],
+                       carries: List, bclass: int):
+        """``(padded, Qb, Gb, maskb, carry0)`` of one launch of ``kind``:
+        the items' requests and ``carries`` padded to ``bclass`` with pad
+        slots, their stacks, and the carry inputs
+        (``CarryPath.launch_inputs``); counts a donated launch."""
+        padded, carries = [it.req for it in items], list(carries)
+        if bclass > len(items):
+            pad_req, pad_carry = self._pad_slot(bucket, padded[0],
+                                                carries[0])
+            padded += [pad_req] * (bclass - len(items))
+            carries += [pad_carry] * (bclass - len(items))
+        Qb = np.stack([r.Qp for r in padded])
+        Gb = np.stack([r.Gp for r in padded])
+        maskb, carry0 = self._path.launch_inputs(
+            carries, np.stack([r.maskp for r in padded]))
+        if self.mesh is None and self._donate_argnums(kind):
+            self.stats.donated_launches += 1
+        return padded, Qb, Gb, maskb, carry0
 
     def _dispatch_swarm(self, bucket, items: List[_PipelineItem]
                         ) -> _LaunchRecord:
@@ -2048,22 +1396,15 @@ class MatcherService:
             fn = self._executable_batch(bucket, bclass)
             compile_hit = self.stats.compile_cache_hits > hits_before
 
-            reqs = [it.req for it in items]
             # a slot's carry: its failed exact carry, its rebased seed,
             # or None for the cold prior
-            carries = [it.carry if it.carry is not None else it.seed
-                       for it in items]
-
-            pad = bclass - B
-            padded = list(reqs)
-            if pad:
-                pad_req, pad_carry = self._pad_slot(bucket, reqs[0],
-                                                    carries[0])
-                padded += [pad_req] * pad
-                carries = carries + [pad_carry] * pad
-                if pad_req is not reqs[0] and self.cfg.early_exit \
-                        and self.cfg.carry_fastpath:
-                    self.stats.pad_slots_frozen += pad
+            padded, Qb, Gb, maskb, carry0 = self._launch_inputs(
+                "batch", bucket, items,
+                [it.carry if it.carry is not None else it.seed
+                 for it in items], bclass)
+            if padded[-1] is not padded[0] and bclass > B \
+                    and self.cfg.early_exit and self.cfg.carry_fastpath:
+                self.stats.pad_slots_frozen += bclass - B
             keys = [r.key for r in padded]
             if self.mesh is None and not all(isinstance(k, np.ndarray)
                                              for k in keys):
@@ -2072,26 +1413,11 @@ class MatcherService:
                 keysb = jnp.stack(keys)
             else:
                 keysb = np.stack([np.asarray(k) for k in keys])
-            Qb = np.stack([r.Qp for r in padded])
-            Gb = np.stack([r.Gp for r in padded])
-            maskb = np.stack([r.maskp for r in padded])
-            if self._pooled:
-                maskb = jnp.asarray(maskb)
-                carry0 = self._pool.assemble(carries, maskb)
-            else:
-                carry0 = self._stack_carries([
-                    c if c is not None
-                    else pso.default_carry(jnp.asarray(r.maskp))
-                    for c, r in zip(carries, padded)])
-            if self.mesh is None and self._donate_argnums("batch"):
-                self.stats.donated_launches += 1
-
             outs = fn(keysb, Qb, Gb, maskb, carry0)
             for it in items:
-                if isinstance(it.seed, _CarryHandle):
-                    # the launch has read the seed row: give it back
-                    it.seed.release()
-                    it.seed = None
+                # the launch has read the seed: give its row back
+                release(it.seed)
+                it.seed = None
             self.stats.batch_launches += 1
             self.stats.batch_problems += B
             self.stats.batch_slots += bclass
@@ -2115,21 +1441,14 @@ class MatcherService:
             orders = [r.order for r in padded]
             crops = [r.crop for r in padded]
             if self.mesh is None:
-                # the program selected each served mapping; every item's
-                # controller state goes to the pool, all B rows in one
-                # program straight from the device outputs, and its
-                # result carries a lazy view of its row
+                # the program selected each served mapping
                 batch_results = collect_served_results(host, B, orders,
                                                        crops)
-                stored = self._pool.write_back(
-                    rec.outs["S_star"], rec.outs["f_star"],
-                    rec.outs["S_bar"], range(B))
-                for r, h in zip(batch_results, stored):
-                    r.carry = _LazyCarry(h)
             else:
-                batch_results = collect_batch_results(host, rec.bclass,
-                                                      orders, crops)
-                stored = [r.carry for r in batch_results[:B]]
+                batch_results = collect_batch_results(
+                    host, rec.bclass, orders, crops)[:B]
+            # every item's controller state is kept for warm starts
+            stored = self._path.keep_served(rec.outs, batch_results)
             done = time.perf_counter()
 
             for j, it in enumerate(items):
@@ -2137,9 +1456,8 @@ class MatcherService:
                 res = ServiceMatchResult(
                     **{f.name: getattr(base, f.name)
                        for f in dataclasses.fields(MatchResult)})
-                if self.warm_start:
-                    self._store_carry(it.req, it.warm_key, stored[j],
-                                      similar=res.found)
+                self._store_carry(it.req, it.warm_key, stored[j],
+                                  similar=res.found)
                 self.stats.epochs_run += res.epochs_run
                 self._note_prune(1, res.prune_sweeps)
                 if res.found:
@@ -2157,17 +1475,6 @@ class MatcherService:
                 # every pipeline launch that preceded this one
                 it.latency_s = done - it.t0
                 it.result = res
-
-    def _launch_batch_legacy(self, bucket, reqs: List[_PendingRequest],
-                             tickets: List[int], results: List) -> None:
-        """The untiered (PR-2) drain path: every request goes straight to
-        one uniform swarm launch. Kept as the ``tiered=False`` baseline —
-        `benchmarks/bench_tiers.py` measures the pipeline against it."""
-        items = self._intake(reqs, tickets)
-        self._launch_swarm(bucket, items)
-        for it in items:
-            it.result.latency_s = it.latency_s
-            results[it.ticket] = it.result
 
     # -- reporting ---------------------------------------------------------
 

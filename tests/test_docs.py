@@ -71,6 +71,8 @@ SEAM_CLASSES = [
     ("repro.core.service", "MatcherService"),
     ("repro.core.service", "CarryStore"),
     ("repro.core.service", "ServiceStats"),
+    ("repro.core.carries", "CarryPath"),
+    ("repro.core.carries", "DeviceCarryPool"),
     ("repro.kernels.backend", "KernelBackend"),
     ("repro.checkpoint.manager", "CheckpointManager"),
     ("repro.core.persist", "AOTCache"),

@@ -13,9 +13,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import graphs, pso
+from repro.core.carries import CarryStore, DeviceCarryPool
 from repro.core.matcher import collect_batch_results, collect_served_results
-from repro.core.service import (CarryStore, DeviceCarryPool, MatcherService,
-                                ServiceStats)
+from repro.core.service import MatcherService, ServiceStats
 from repro.kernels import pallas_compat
 
 jax.config.update("jax_platform_name", "cpu")
@@ -126,18 +126,24 @@ def _result_fingerprint(r):
             r.found, r.tier, r.f_star, r.epochs_run)
 
 
-def test_pipelined_matches_serial_bitwise():
+@pytest.mark.parametrize("tiered", [True, False],
+                         ids=["tiered", "untiered"])
+def test_pipelined_matches_serial_bitwise(tiered):
     """Async dispatch must not change a single bit of any result: a
     mixed easy/hard two-bucket burst produces identical mappings, tiers,
-    f* and epoch counts through both drain arms, cold AND warm."""
+    f* and epoch counts through both resolve choices of the tier walk,
+    cold AND warm, with the tiers on or off."""
     specs = [(s, n, m) for n, m in BUCKET_ARGS for s in range(5)]
-    pipe = MatcherService(CFG)
-    ser = MatcherService(CFG, pipelined=False)
+    pipe = MatcherService(CFG, tiered=tiered)
+    ser = MatcherService(CFG, tiered=tiered, pipelined=False)
     for _round in range(3):
         rp = _burst(pipe, specs)
         rs = _burst(ser, specs)
         for a, b in zip(rp, rs):
             assert _result_fingerprint(a) == _result_fingerprint(b)
+    if not tiered:
+        assert all(r.tier == 2 for r in rp)
+        assert pipe.stats.tier0.launches == ser.stats.tier0.launches == 0
 
 
 # ---------------------------------------------------------------------------

@@ -11,8 +11,9 @@ from repro.accel import EDGE
 from repro.accel.target_graph import (free_engine_graph,
                                       free_engine_signature)
 from repro.core import graphs, preemptible_dag, pso
+from repro.core.carries import CarryStore
 from repro.core.graphs import compatibility_mask
-from repro.core.service import CarryStore, MatcherService, ServiceStats
+from repro.core.service import MatcherService, ServiceStats
 from repro.core.pso import PSOConfig
 from repro.sched import SimConfig, Simulator, get_scheduler
 from repro.sched.tasks import fixed_scenario, make_mixed_burst_scenario
@@ -241,6 +242,11 @@ def test_tiered_drain_matches_untiered_per_problem():
         if rt.found:
             np.testing.assert_array_equal(np.asarray(rt.mapping),
                                           np.asarray(ru.mapping))
+    # the untiered walk runs no Tier-0/1 launch: every problem swarms
+    su = svc_u.stats_dict()
+    assert su["tier0_launches"] == su["tier1_launches"] == 0
+    assert su["tier2_checked"] == su["batch_problems"] == su["calls"] == 8
+    assert all(r.tier == 2 for r in warm_u)
 
 
 def test_tier1_rebase_after_engine_drift():
